@@ -38,7 +38,7 @@ def run(duration: float = 420.0, rate: float = 5.0, seed: int = 0,
                        max_num_seqs=8, num_blocks=512, block_size=16,
                        max_model_len=8192, max_instances=6)
 
-    def factory(cfg, tp):
+    def factory(cfg, tp, gpu):
         ex = SimExecutor(cfg, GPU_L40S, tp=2, efficiency=0.5)
         return LLMEngine(cfg, ex, num_blocks=spec.num_blocks,
                          block_size=spec.block_size,

@@ -65,7 +65,7 @@ def build_plane(disaggregated: bool, total: int = 4, prefill: int = 2,
     from repro.engine.engine import LLMEngine
     from repro.engine.executor import SimExecutor
 
-    def factory(cfg, tp):
+    def factory(cfg, tp, gpu):
         ex = SimExecutor(cfg, node_cfg["hardware"], tp=node_cfg["tp"],
                          efficiency=node_cfg["efficiency"])
         return LLMEngine(cfg, ex, num_blocks=spec.num_blocks,

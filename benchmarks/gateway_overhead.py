@@ -91,7 +91,7 @@ def build_skewed_plane(policy: str, node: str = "GPU-L",
         hbm_bandwidth=hw.hbm_bandwidth * slow_factor,
         link_bandwidth=hw.link_bandwidth * slow_factor)
 
-    def factory(cfg, tp):
+    def factory(cfg, tp, gpu):
         ex = SimExecutor(cfg, hw if next(built) % 2 == 0 else slow_hw,
                          tp=node_cfg["tp"],
                          efficiency=node_cfg["efficiency"])

@@ -30,8 +30,8 @@ def paged_attention_bench(s=16, h=16, kv=8, d=128, bs=32, mb=64):
     nb = s * mb + 1
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(s, h, d)), jnp.float32)
-    pk = jnp.asarray(rng.normal(size=(nb, bs, kv, d)), jnp.float32)
-    pv = jnp.asarray(rng.normal(size=(nb, bs, kv, d)), jnp.float32)
+    pk = jnp.asarray(rng.normal(size=(nb, kv, bs, d)), jnp.float32)
+    pv = jnp.asarray(rng.normal(size=(nb, kv, bs, d)), jnp.float32)
     bt = jnp.asarray(rng.integers(0, nb, size=(s, mb)), jnp.int32)
     lens = jnp.full((s,), mb * bs, jnp.int32)
     f = jax.jit(paged_attention_ref)

@@ -58,7 +58,7 @@ def build_plane(total: int = 4, prefill: int = 0, node: str = "GPU-L",
     from repro.engine.engine import LLMEngine
     from repro.engine.executor import SimExecutor
 
-    def factory(cfg, tp):
+    def factory(cfg, tp, gpu):
         ex = SimExecutor(cfg, node_cfg["hardware"], tp=node_cfg["tp"],
                          efficiency=node_cfg["efficiency"])
         return LLMEngine(cfg, ex, num_blocks=spec.num_blocks,

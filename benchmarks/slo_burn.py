@@ -76,7 +76,7 @@ def build_plane(mode: str, sanitize: bool = False,
                        max_instances=max_replicas, services=services,
                        sanitize=sanitize)
 
-    def factory(cfg, tp):
+    def factory(cfg, tp, gpu):
         ex = SimExecutor(cfg, GPU_L40S, tp=2, efficiency=0.5)
         return LLMEngine(cfg, ex, num_blocks=spec.num_blocks,
                          block_size=spec.block_size,

@@ -43,7 +43,7 @@ def build_plane(node_cfg: dict) -> ControlPlane:
                        max_model_len=32_768,
                        max_prefill_tokens=MAX_BATCHED_TOKENS)
 
-    def factory(cfg, tp):
+    def factory(cfg, tp, gpu):
         ex = SimExecutor(cfg, node_cfg["hardware"], tp=node_cfg["tp"],
                          efficiency=node_cfg["efficiency"])
         return LLMEngine(cfg, ex, num_blocks=spec.num_blocks,

@@ -3,6 +3,11 @@ serve a few chat completions through the OpenAI-compatible API layer with
 REAL model compute.
 
     PYTHONPATH=src python examples/quickstart.py [--arch qwen3-1.7b]
+    PYTHONPATH=src python examples/quickstart.py --cpu-rehearsal
+
+By default the config runs as published on the local chip, with the
+compiled Pallas kernel; --cpu-rehearsal runs the reduced config with
+interpreted kernels on the CPU.
 
 What happens (paper §3): a declarative `ModelDeploymentSpec` is applied
 through the kubectl-shaped `AdminClient`; the Reconciler converges it into
@@ -25,33 +30,30 @@ import numpy as np
 from repro import configs
 from repro.api import (AdminClient, APIStatusError, ChatMessage,
                        ServingClient)
-from repro.config import TPU_V5E
 from repro.core.controller import ClusterSpec, ControlPlane
-from repro.engine.engine import LLMEngine
-from repro.engine.executor import RealExecutor
-from repro.models import api
+from repro.engine.factory import real_engine_factory, serving_setup
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b",
                     choices=list(configs.CONFIGS))
+    ap.add_argument("--cpu-rehearsal", action="store_true",
+                    help="reduced config, interpreted kernels, CPU")
     args = ap.parse_args()
 
-    cfg = configs.get(args.arch).reduced()   # CPU-sized
-    print(f"[1/4] init reduced {args.arch}: "
-          f"{cfg.num_layers}L d={cfg.d_model}")
-    params, _ = api.init_params(cfg, jax.random.key(0))
-
-    def factory(c, tp):
-        ex = RealExecutor(c, params, num_blocks=256, block_size=16,
-                          hw=TPU_V5E, max_model_len=256, max_slots=8)
-        return LLMEngine(c, ex, num_blocks=256, block_size=16,
-                         max_num_seqs=8, max_prefill_tokens=128,
-                         max_model_len=256)
+    if args.cpu_rehearsal:
+        jax.config.update("jax_platforms", "cpu")
+    cfg, hw, backend = serving_setup(configs.get(args.arch), jax.devices()[0],
+                                     cpu_rehearsal=args.cpu_rehearsal)
+    print(f"[1/4] {args.arch}: {cfg.num_layers}L d={cfg.d_model} "
+          f"on {jax.devices()[0].device_kind}")
+    factory = real_engine_factory(cfg, jax.devices()[:1], hw=hw,
+                                  backend=backend)
 
     print("[2/4] bringing up control plane (slurm sim + microservices)")
-    cp = ControlPlane(ClusterSpec(num_nodes=2, gpus_per_node=1),
+    cp = ControlPlane(ClusterSpec(num_nodes=1, gpus_per_node=1,
+                                  hardware=hw),
                       engine_factory=factory)
     cp.add_tenant("demo", "sk-demo")
     cp.register_model(cfg)

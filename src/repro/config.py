@@ -323,10 +323,28 @@ class HardwareConfig:
                    bytes_coll / self.link_bandwidth if self.link_bandwidth else 0.0)
 
 
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s, 1,600 Gbit/s of interconnect per chip (four 50 GB/s ICI links).
 TPU_V5E = HardwareConfig("tpu-v5e", 197e12, 819e9, 50e9, 16e9)
 # Paper's two benchmark configurations (Table 1); dense-bf16 peaks
 # (the 2x "with sparsity" datasheet figures halved where applicable).
 GPU_L40S = HardwareConfig("l40s", 181e12, 864e9, 64e9, 48e9)
 GPU_H100 = HardwareConfig("h100-sxm", 989e12, 3350e9, 450e9, 80e9)
 
-HARDWARE = {h.name: h for h in (TPU_V5E, GPU_L40S, GPU_H100)}
+#: roofline constants keyed by the `device_kind` JAX reports for the chip
+HARDWARE = {
+    "TPU v5 lite": TPU_V5E,
+    "NVIDIA L40S": GPU_L40S,
+    "NVIDIA H100 80GB HBM3": GPU_H100,
+}
+
+
+def hardware_for(device) -> HardwareConfig:
+    """The roofline constants of a `jax.Device`; a kind that is not in the
+    table is an error, never a default."""
+    try:
+        return HARDWARE[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware entry for device kind {device.device_kind!r} "
+            f"(known: {sorted(HARDWARE)})") from None

@@ -1,4 +1,4 @@
-"""qwen3-1.7b [dense] — qk_norm, GQA. [hf:Qwen/Qwen3-8B; hf]"""
+"""qwen3-1.7b [dense] — qk_norm, GQA. [hf:Qwen/Qwen3-1.7B config.json]"""
 from repro.config import ModelConfig
 
 CONFIG = ModelConfig(

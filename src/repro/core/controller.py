@@ -4,9 +4,10 @@ Layer 1 (Kubernetes microservices): Web Gateway, Job Worker, Slurm Submit,
 Endpoint Gateway, Endpoint Worker, Metrics Gateway, Autoscaler, central DB.
 Layer 2 (Slurm jobs): vLLM engine instances spawned on simulated HPC nodes.
 
-The engine executor is injectable: SimExecutor (roofline timing, used by the
-Table-1 benchmarks) or RealExecutor (actual JAX compute, used in tests and
-examples with reduced configs).
+The engine executor is injectable through `engine_factory(cfg, tp, gpu)`,
+where `gpu` is the job's cluster-wide GPU slot: SimExecutor (roofline
+timing, used by the Table-1 benchmarks) or RealExecutor (actual JAX
+compute, one replica per device; see `repro.engine.factory`).
 """
 from __future__ import annotations
 
@@ -76,6 +77,7 @@ class ControlPlane:
         nodes = [SimNode(f"node{i:03d}", gpus=self.spec.gpus_per_node,
                          partition=self.spec.partition)
                  for i in range(self.spec.num_nodes)]
+        self._node_index = {n.node_id: i for i, n in enumerate(nodes)}
         self.slurm = SimSlurm(self.loop, nodes,
                               sched_interval=self.spec.slurm_sched_interval)
         self.endpoint_gateway = EndpointGateway(self.db, self.loop)
@@ -267,7 +269,15 @@ class ControlPlane:
                 cost.decode_time(1, n + req.target_len()))
 
     # ------------------------------------------------------------------
-    def _default_engine(self, cfg: ModelConfig, tp: int) -> LLMEngine:
+    def _gpu_slot(self, job, node) -> int:
+        """Cluster-wide index of the job's first GPU: node position ×
+        gpus_per_node + its index on the node. An engine factory maps it
+        to a device (`jax.devices()[slot]` on a one-host cluster)."""
+        return (self._node_index[node.node_id] * self.spec.gpus_per_node
+                + job.gpu_ids[0])
+
+    def _default_engine(self, cfg: ModelConfig, tp: int,
+                        gpu: int) -> LLMEngine:
         ex = SimExecutor(cfg, self.spec.hardware, tp=tp)
         return LLMEngine(cfg, ex, num_blocks=self.spec.num_blocks,
                          block_size=self.spec.block_size,
@@ -287,7 +297,8 @@ class ControlPlane:
         if port is None:
             return lambda: None
         cfg = self.model_cfgs[params["model"]]
-        engine = self._engine_factory(cfg, int(params.get("gpus", 1)))
+        engine = self._engine_factory(cfg, int(params.get("gpus", 1)),
+                                      self._gpu_slot(job, node))
         # hierarchical KV: hang the host+shared tiers off the allocator so
         # eviction demotes and match_prefix misses promote (default off —
         # the legacy add_model path has no deployment spec, hence no tiers)

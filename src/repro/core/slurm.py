@@ -31,11 +31,23 @@ class SimNode:
     gpus: int = 4
     partition: str = "gpu"
     up: bool = True
-    gpus_used: int = 0
+    # GPU indices held by running jobs (Slurm's per-job GRES binding)
+    busy_gpus: set = field(default_factory=set)
+
+    @property
+    def gpus_used(self) -> int:
+        return len(self.busy_gpus)
 
     @property
     def gpus_free(self) -> int:
         return self.gpus - self.gpus_used if self.up else 0
+
+    def bind_gpus(self, n: int) -> tuple:
+        """Take the n lowest free GPU indices."""
+        ids = tuple(i for i in range(self.gpus)
+                    if i not in self.busy_gpus)[:n]
+        self.busy_gpus.update(ids)
+        return ids
 
 
 @dataclass(eq=False)
@@ -45,6 +57,7 @@ class SlurmJob:
     on_start: Callable                # fn(job, node) -> on_kill callable
     state: JobState = JobState.PENDING
     node: Optional[SimNode] = None
+    gpu_ids: tuple = ()               # GPU indices on `node` (SLURM_JOB_GPUS)
     submitted_at: float = 0.0
     started_at: Optional[float] = None
     _on_kill: Optional[Callable] = None
@@ -112,7 +125,7 @@ class SimSlurm:
                          and n.gpus_free >= job.gpus), None)
             if node is None:
                 continue  # stays pending (FIFO, no backfill)
-            node.gpus_used += job.gpus
+            job.gpu_ids = node.bind_gpus(job.gpus)
             job.node = node
             job.state = JobState.RUNNING
             job.started_at = self.loop.now
@@ -125,7 +138,7 @@ class SimSlurm:
 
     def _teardown(self, job: SlurmJob, state: JobState):
         if job.node is not None and job.state == JobState.RUNNING:
-            job.node.gpus_used -= job.gpus
+            job.node.busy_gpus.difference_update(job.gpu_ids)
         job.state = state
         if job._on_kill is not None:
             job._on_kill()
@@ -138,7 +151,7 @@ class SimSlurm:
         for job in list(self.jobs.values()):
             if job.node is node and job.state == JobState.RUNNING:
                 self._teardown(job, JobState.NODE_FAIL)
-        node.gpus_used = 0
+        node.busy_gpus.clear()
 
     def restore_node(self, node_id: str):
         self.nodes[node_id].up = True

@@ -1,9 +1,11 @@
 """Model executors behind the engine.
 
-RealExecutor   — actual JAX compute against the paged pool (dense/vlm/moe) or
-                 slot-dense caches (ssm/hybrid/audio). Used with reduced
-                 configs on CPU in tests/examples; the identical code path
-                 runs sharded on TPU.
+RealExecutor   — actual JAX compute on one device against the paged pool
+                 (dense/vlm/moe) or slot-dense caches (ssm/hybrid/audio).
+                 On a TPU it runs the compiled Pallas paged-attention
+                 kernel; tests and the CPU rehearsal name `ref` or
+                 `interpret` instead. One replica holds one whole device:
+                 nothing is sharded across chips yet.
 SimExecutor    — no compute; the roofline cost model supplies step times and
                  the engine synthesises token ids. Used by the Table-1-scale
                  virtual-clock benchmarks (50 runs × 1000 concurrency would
@@ -59,10 +61,21 @@ class RealExecutor:
 
     def __init__(self, cfg: ModelConfig, params, num_blocks: int,
                  block_size: int, hw: HardwareConfig, tp: int = 1,
-                 backend: str = "ref", max_model_len: int = 4096,
-                 max_slots: int = 64):
+                 backend: Optional[str] = None, max_model_len: int = 4096,
+                 max_slots: int = 64, device=None):
+        """`params` must already live on `device` (default: the first
+        device). `backend` None means the compiled Pallas kernel, which
+        only a TPU device runs; elsewhere name `ref` or `interpret`."""
         from repro.engine import paged_model
         from repro.models import api
+        self.device = device if device is not None else jax.devices()[0]
+        if backend is None:
+            if self.device.platform != "tpu":
+                raise ValueError(
+                    f"no compiled paged-attention kernel for "
+                    f"{self.device.platform!r}; pass backend='ref' or "
+                    f"backend='interpret'")
+            backend = "pallas"
         self.cfg = cfg
         self.params = params
         self.block_size = block_size
@@ -72,14 +85,24 @@ class RealExecutor:
         self.api = api
         self.paged = cfg.family in ("dense", "vlm", "moe")
         self.max_model_len = max_model_len
+        self.max_slots = max_slots
         if self.paged:
-            self.pool = paged_model.init_pool(cfg, num_blocks, block_size)
+            # one block past the allocator's: the rows that pad a decode
+            # batch write and read there and nowhere else
+            self.pad_block = num_blocks
+            self.pool = paged_model.init_pool(cfg, num_blocks + 1,
+                                              block_size, device=self.device)
             self._paged_model = paged_model
             self.mb = -(-max_model_len // block_size)
         else:
             # state executor: one dense/state cache slab over all slots
-            self.cache = api.init_cache(cfg, max_slots, max_model_len,
-                                        dtype=jnp.float32)
+            with jax.default_device(self.device):
+                self.cache = api.init_cache(cfg, max_slots, max_model_len,
+                                            dtype=jnp.float32)
+
+    def _put(self, x):
+        """Host ids/positions/block tables -> int32 on this device."""
+        return jax.device_put(np.asarray(x, np.int32), self.device)
 
     # ------------------------------------------------------------------
     def step(self, prefills: list, decode: Optional[dict]):
@@ -105,11 +128,11 @@ class RealExecutor:
             # chunked prefill: timing per chunk; compute happens once on the
             # final chunk (whole-prompt recompute — numerically identical)
             return None
-        toks = jnp.asarray(np.asarray(pf["token_ids"], np.int32))[None]
+        toks = self._put(pf["token_ids"])[None]
         logits, cache = self.api.prefill_fn(self.params, self.cfg,
                                             {"tokens": toks})
         if self.paged:
-            bt = jnp.asarray(np.asarray(pf["block_table"], np.int32))
+            bt = self._put(pf["block_table"])
             self.pool = self._paged_model.write_prefill(
                 self.pool, cache, bt, self.block_size)
         else:
@@ -122,18 +145,28 @@ class RealExecutor:
 
     def _decode(self, dec: dict):
         slots, tokens, pos = dec["slots"], dec["tokens"], dec["pos"]
-        toks = jnp.asarray(np.asarray(tokens, np.int32))
-        posv = jnp.asarray(np.asarray(pos, np.int32))
         if self.paged:
-            bt = np.zeros((len(slots), self.mb), np.int32)
+            # The batch is always max_slots rows, so a sequence's logits
+            # come from one program whatever else is in the batch: on the
+            # TPU a program compiled for another batch size rounds
+            # differently, and greedy tokens then depend on the load.
+            n = len(slots)
+            if n > self.max_slots:
+                raise ValueError(f"decode batch {n} > max_slots "
+                                 f"{self.max_slots}")
+            bt = np.full((self.max_slots, self.mb), self.pad_block, np.int32)
             for i, table in enumerate(dec["block_tables"]):
                 bt[i, :len(table)] = table
+            pad = [0] * (self.max_slots - n)
             logits, self.pool = self._paged_model.decode_step(
-                self.params, self.cfg, toks, posv, self.pool,
-                jnp.asarray(bt), backend=self.backend)
-            return np.asarray(logits)
+                self.params, self.cfg, self._put(list(tokens) + pad),
+                self._put(list(pos) + pad), self.pool, self._put(bt),
+                backend=self.backend)
+            return np.asarray(logits[:n])
+        toks = self._put(tokens)
+        posv = self._put(pos)
         # state executor: gather slot caches, run decode_fn, scatter back
-        sl = jnp.asarray(np.asarray(slots, np.int32))
+        sl = self._put(slots)
         cache = jax.tree.map(lambda slab: slab[:, sl], self.cache)
         logits, cache = self.api.decode_fn(self.params, self.cfg, toks,
                                            cache, posv)

@@ -2,9 +2,13 @@
 
 Supports the dense / vlm / moe families (the ones with a KV cache the paper
 technique applies to). Decode runs one token per active slot against the
-pool via the paged-attention kernel (ref backend on this container's CPU,
-Pallas on TPU). SSM/hybrid/audio families are served through the dense
-state executor instead (see DESIGN.md §Arch-applicability).
+pool via the paged-attention kernel, whose backend the caller names (the
+compiled Pallas kernel on a TPU; `ref` or `interpret` on the CPU).
+SSM/hybrid/audio families are served through the dense state executor
+instead.
+
+The pool is head-major, (L, NB, KV, BS, D), the layout the Pallas kernel
+tiles.
 """
 from __future__ import annotations
 
@@ -21,10 +25,11 @@ from repro.models import moe as moe_mod
 
 
 def init_pool(cfg: ModelConfig, num_blocks: int, block_size: int,
-              dtype=jnp.float32):
-    shape = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads,
+              dtype=jnp.float32, device=None):
+    shape = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_size,
              cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return {"k": jnp.zeros(shape, dtype, device=device),
+            "v": jnp.zeros(shape, dtype, device=device)}
 
 
 @functools.partial(jax.jit, static_argnames=("block_size",))
@@ -39,8 +44,8 @@ def write_prefill(pool, cache, block_table, block_size: int):
         nb = block_table.shape[0]
         pad = nb * block_size - t
         c = jnp.pad(cache_x[:, 0], ((0, 0), (0, pad), (0, 0), (0, 0)))
-        c = c.reshape(l, nb, block_size, kvh, d).astype(pool_x.dtype)
-        return pool_x.at[:, block_table].set(c)
+        c = c.reshape(l, nb, block_size, kvh, d).transpose(0, 1, 3, 2, 4)
+        return pool_x.at[:, block_table].set(c.astype(pool_x.dtype))
 
     return {
         "k": scatter(pool["k"], cache["k"]),
@@ -49,12 +54,12 @@ def write_prefill(pool, cache, block_table, block_size: int):
 
 
 def decode_step(params, cfg: ModelConfig, tokens, pos, pool, block_tables,
-                backend: str = "ref"):
+                *, backend: str):
     """tokens/pos: (S,); pool as init_pool; block_tables: (S, MB).
     Returns (logits (S, V), new pool)."""
     x = cm.embed(params["embedding"], tokens[:, None])   # (S, 1, d)
     s = tokens.shape[0]
-    bs = pool["k"].shape[2]
+    bs = pool["k"].shape[3]
     blk = jnp.take_along_axis(block_tables, (pos // bs)[:, None], axis=1)[:, 0]
     off = pos % bs
     ctx = pos + 1
@@ -63,8 +68,9 @@ def decode_step(params, cfg: ModelConfig, tokens, pos, pool, block_tables,
         lp, pk, pv = inp
         h = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
         q, k, v = cm._qkv(lp["attn"], cfg, h, pos[:, None])
-        pk = pk.at[blk, off].set(k[:, 0].astype(pk.dtype))
-        pv = pv.at[blk, off].set(v[:, 0].astype(pv.dtype))
+        # (NB, KV, BS, D) indexed [blk, :, off] -> (S, KV, D)
+        pk = pk.at[blk, :, off].set(k[:, 0].astype(pk.dtype))
+        pv = pv.at[blk, :, off].set(v[:, 0].astype(pv.dtype))
         a = pa_ops.paged_attention(q[:, 0], pk, pv, block_tables, ctx,
                                    backend=backend)
         x = x + jnp.einsum("shd,hdo->so", a, lp["attn"]["wo"])[:, None]

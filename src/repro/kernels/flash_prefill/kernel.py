@@ -8,7 +8,9 @@ window, entirely below it) are skipped with pl.when, so the kernel does
 adaptation of the paper's prefill hot loop.
 
 Block shapes default to (128, head_dim) q-tiles × (512, head_dim) k-tiles,
-(8,128)-aligned for the MXU.
+(8,128)-aligned for the MXU. Operands are head-major inside the kernel,
+(B, KV, T*QPK, D) for q and (B, KV, T, D) for k/v, so each tile's last two
+dims are whole (rows, head_dim) slabs, as Mosaic's tiling requires.
 """
 from __future__ import annotations
 
@@ -18,9 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax 0.4.x names this TPUCompilerParams; 0.5+ renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -43,9 +42,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
     @pl.when(causal_live & window_live)
     def _compute():
-        q = q_ref[0, :, 0].astype(jnp.float32)         # (bq*qpk, D) flattened
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (bk, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)            # (bq*qpk, D) flattened
+        k = k_ref[0, 0].astype(jnp.float32)            # (bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         d = q.shape[-1]
         scale = d ** -0.5
         qk = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -72,14 +71,14 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        o_ref[0, :, 0] = (acc_ref[...] /
-                          jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] /
+                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "bq", "bk",
                                              "interpret"))
 def flash_prefill(q, k, v, window: int = 0, bq: int = 128, bk: int = 512,
-                  interpret: bool = True):
+                  interpret: bool = False):
     """q: (B, T, H, D); k/v: (B, T, KV, D) -> (B, T, H, D)."""
     b, t, h, d = q.shape
     kvh = k.shape[2]
@@ -89,10 +88,12 @@ def flash_prefill(q, k, v, window: int = 0, bq: int = 128, bk: int = 512,
     assert t % bq == 0 and t % bk == 0, (t, bq, bk)
     nq, nk = t // bq, t // bk
 
-    # group q rows by kv head: (B, T, KV, QPK, D) -> (B, T*?, ...) — use a
-    # (bq*qpk, d) flat tile per (b, kv) so the MXU sees one tall matmul.
+    # group q rows by kv head, (B, T, KV, QPK, D) -> (B, KV, T*QPK, D): one
+    # (bq*qpk, d) flat tile per (b, kv) so the MXU sees one tall matmul
     qg = q.reshape(b, t, kvh, qpk, d).transpose(0, 2, 1, 3, 4) \
-          .reshape(b, kvh, t * qpk, d).transpose(0, 2, 1, 3)  # (B, T*QPK, KV, D)
+          .reshape(b, kvh, t * qpk, d)
+    kg = k.transpose(0, 2, 1, 3)                        # (B, KV, T, D)
+    vg = v.transpose(0, 2, 1, 3)
 
     grid = (b, kvh, nq, nk)
 
@@ -101,27 +102,26 @@ def flash_prefill(q, k, v, window: int = 0, bq: int = 128, bk: int = 512,
                           qpk=qpk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq * qpk, 1, d),
-                         lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda bi, hi, qi, ki: (bi, ki, hi, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda bi, hi, qi, ki: (bi, ki, hi, 0)),
+            pl.BlockSpec((1, 1, bq * qpk, d),
+                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq * qpk, 1, d),
-                               lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, t * qpk, kvh, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq * qpk, d),
+                               lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, t * qpk, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq * qpk, 1), jnp.float32),
             pltpu.VMEM((bq * qpk, 1), jnp.float32),
             pltpu.VMEM((bq * qpk, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(qg, k, v)
+    )(qg, kg, vg)
 
-    out = out.transpose(0, 2, 1, 3).reshape(b, kvh, t, qpk, d) \
-             .transpose(0, 2, 1, 3, 4).reshape(b, t, h, d)
-    return out
+    return out.reshape(b, kvh, t, qpk, d).transpose(0, 2, 1, 3, 4) \
+              .reshape(b, t, h, d)

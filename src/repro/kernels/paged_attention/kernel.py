@@ -1,9 +1,11 @@
 """Pallas TPU paged-attention decode kernel.
 
-TPU adaptation of vLLM's PagedAttention (DESIGN.md §2): instead of GPU
-pointer-chasing gathers, the block table is *scalar-prefetched* and drives
-each step's BlockSpec index_map, so the needed KV blocks are DMA'd
-HBM->VMEM as dense (block_size, head_dim) tiles that keep the MXU/VPU fed.
+TPU adaptation of vLLM's PagedAttention: instead of GPU pointer-chasing
+gathers, the block table is *scalar-prefetched* and drives each step's
+BlockSpec index_map, so the needed KV blocks are DMA'd HBM->VMEM as dense
+(block_size, head_dim) tiles that keep the MXU/VPU fed. The pool is
+head-major, (NB, KV, BS, D), so a tile's last two dims are whole
+(block_size, head_dim) slabs, as Mosaic's (8, 128) tiling requires.
 
 Grid: (seqs, kv_heads, num_pages). The page axis is `arbitrary` (sequential)
 so a flash-style running softmax accumulates in VMEM scratch; pages past
@@ -18,9 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax 0.4.x names this TPUCompilerParams; 0.5+ renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -44,8 +43,8 @@ def _kernel(block_tables_ref, lens_ref,       # scalar prefetch
     @pl.when(page * bs < ctx)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # (QPK, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)       # (BS, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)          # (BS, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         scale = q.shape[-1] ** -0.5
         qk = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
@@ -74,15 +73,15 @@ def _kernel(block_tables_ref, lens_ref,       # scalar prefetch
 @functools.partial(jax.jit,
                    static_argnames=("interpret",))
 def paged_attention(q, pool_k, pool_v, block_tables, context_lens,
-                    *, interpret: bool = True):
-    """q: (S, H, D); pool_k/v: (NB, BS, KV, D); block_tables: (S, MB);
+                    *, interpret: bool = False):
+    """q: (S, H, D); pool_k/v: (NB, KV, BS, D); block_tables: (S, MB);
     context_lens: (S,). Returns (S, H, D).
 
-    interpret=True runs the kernel body in Python on CPU (the validation
-    mode for this container); on a real TPU pass interpret=False.
+    interpret=True runs the kernel body in Python on the CPU (the
+    validation mode of the CPU tests); the default compiles it for the TPU.
     """
     s, h, d = q.shape
-    nb, bs, kv, _ = pool_k.shape
+    nb, kv, bs, _ = pool_k.shape
     mb = block_tables.shape[1]
     qpk = h // kv
     qg = q.reshape(s, kv, qpk, d)
@@ -93,7 +92,7 @@ def paged_attention(q, pool_k, pool_v, block_tables, context_lens,
         return (si, hi, 0, 0)
 
     def kv_map(si, hi, pi, bt, lens):
-        return (bt[si, pi], 0, hi, 0)
+        return (bt[si, pi], hi, 0, 0)
 
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, pages=mb),
@@ -102,8 +101,8 @@ def paged_attention(q, pool_k, pool_v, block_tables, context_lens,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, qpk, d), q_map),
-                pl.BlockSpec((1, bs, 1, d), kv_map),
-                pl.BlockSpec((1, bs, 1, d), kv_map),
+                pl.BlockSpec((1, 1, bs, d), kv_map),
+                pl.BlockSpec((1, 1, bs, d), kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, qpk, d), q_map),
             scratch_shapes=[
@@ -113,7 +112,7 @@ def paged_attention(q, pool_k, pool_v, block_tables, context_lens,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((s, kv, qpk, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, context_lens, qg, pool_k, pool_v)

@@ -2,7 +2,7 @@
 
 Layouts (TPU-native):
   q            : (S, H, D)          one new token per sequence
-  pool_k/v     : (NB, BS, KV, D)    global block pool
+  pool_k/v     : (NB, KV, BS, D)    global block pool, head-major
   block_tables : (S, MB) int32      logical page -> physical block
   context_lens : (S,)   int32       tokens valid per sequence (incl. new)
 
@@ -16,18 +16,16 @@ import jax.numpy as jnp
 
 def paged_attention_ref(q, pool_k, pool_v, block_tables, context_lens):
     s, h, d = q.shape
-    nb, bs, kv, _ = pool_k.shape
+    nb, kv, bs, _ = pool_k.shape
     mb = block_tables.shape[1]
     qpk = h // kv
 
-    k = pool_k[block_tables]                      # (S, MB, BS, KV, D)
-    v = pool_v[block_tables]
-    k = k.reshape(s, mb * bs, kv, d)
-    v = v.reshape(s, mb * bs, kv, d)
+    def gather(pool):                    # (S, MB, KV, BS, D) -> (S, KV, MB*BS, D)
+        x = pool[block_tables].transpose(0, 2, 1, 3, 4)
+        return x.reshape(s, kv, mb * bs, d).astype(jnp.float32)
 
+    kg, vg = gather(pool_k), gather(pool_v)
     qg = q.reshape(s, kv, qpk, d).astype(jnp.float32)
-    kg = jnp.moveaxis(k, 2, 1).astype(jnp.float32)  # (S, KV, MB*BS, D)
-    vg = jnp.moveaxis(v, 2, 1).astype(jnp.float32)
 
     logits = jnp.einsum("skqd,sktd->skqt", qg, kg) * (d ** -0.5)
     valid = (jnp.arange(mb * bs)[None, :] < context_lens[:, None])

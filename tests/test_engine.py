@@ -52,7 +52,8 @@ def test_paged_engine_matches_oracle(dense_setup, rng):
                for n in (11, 64)]
     oracle = [oracle_generate(cfg, params, p, 6) for p in prompts]
     ex = RealExecutor(cfg, params, num_blocks=256, block_size=16,
-                      hw=TPU_V5E, max_model_len=256, max_slots=8)
+                      hw=TPU_V5E, max_model_len=256, max_slots=8,
+                      backend="ref")
     eng = LLMEngine(cfg, ex, num_blocks=256, block_size=16, max_num_seqs=8,
                     max_prefill_tokens=32, max_model_len=256)
     reqs = [Request(prompt_tokens=p,
@@ -67,6 +68,40 @@ def test_paged_engine_matches_oracle(dense_setup, rng):
     assert eng.allocator.num_free() == 256
 
 
+def test_decode_batch_padded_to_max_slots(dense_setup, rng, monkeypatch):
+    """Every decode step runs max_slots rows, so one program serves every
+    batch; the padding rows leave a sequence's tokens as they were alone."""
+    from repro.engine import paged_model
+    cfg, params = dense_setup
+    rows = []
+    decode_step = paged_model.decode_step
+
+    def spy(params, cfg, tokens, *args, **kw):
+        rows.append(tokens.shape[0])
+        return decode_step(params, cfg, tokens, *args, **kw)
+
+    monkeypatch.setattr(paged_model, "decode_step", spy)
+    prompts = [list(rng.integers(1, cfg.vocab_size, size=n))
+               for n in (5, 19, 33)]
+    first = {}
+    for n in (1, 3):
+        ex = RealExecutor(cfg, params, num_blocks=32, block_size=8,
+                          hw=TPU_V5E, max_model_len=64, max_slots=4,
+                          backend="ref")
+        eng = LLMEngine(cfg, ex, num_blocks=32, block_size=8,
+                        max_num_seqs=4, max_prefill_tokens=64,
+                        max_model_len=64)
+        reqs = [Request(prompt_tokens=p,
+                        sampling=SamplingParams(temperature=0.0,
+                                                max_new_tokens=5))
+                for p in prompts[:n]]
+        run_engine(eng, reqs)
+        assert all(r.status.value == "finished" for r in reqs)
+        first[n] = reqs[0].output_tokens
+    assert rows and set(rows) == {4}
+    assert first[1] == first[3]
+
+
 def test_state_executor_matches_oracle(rng):
     """ssm family goes through the slot-state executor, not the paged pool."""
     cfg = configs.get("mamba2-780m").reduced()
@@ -75,7 +110,8 @@ def test_state_executor_matches_oracle(rng):
                for n in (9, 17)]
     oracle = [oracle_generate(cfg, params, p, 5) for p in prompts]
     ex = RealExecutor(cfg, params, num_blocks=64, block_size=16,
-                      hw=TPU_V5E, max_model_len=128, max_slots=4)
+                      hw=TPU_V5E, max_model_len=128, max_slots=4,
+                      backend="ref")
     eng = LLMEngine(cfg, ex, num_blocks=64, block_size=16, max_num_seqs=4,
                     max_prefill_tokens=64, max_model_len=128,
                     enable_prefix_caching=False)
@@ -93,7 +129,8 @@ def test_preemption_under_block_pressure(dense_setup, rng):
     # 3 seqs prefill into 15/16 blocks; decode growth forces eviction
     cfg, params = dense_setup
     ex = RealExecutor(cfg, params, num_blocks=16, block_size=8, hw=TPU_V5E,
-                      max_model_len=96, max_slots=4)
+                      max_model_len=96, max_slots=4,
+                      backend="ref")
     eng = LLMEngine(cfg, ex, num_blocks=16, block_size=8, max_num_seqs=4,
                     max_prefill_tokens=64, max_model_len=96,
                     enable_prefix_caching=False)
@@ -120,7 +157,8 @@ def test_prefix_caching_does_not_change_outputs(dense_setup, rng):
     outs = {}
     for caching in (False, True):
         ex = RealExecutor(cfg, params, num_blocks=128, block_size=8,
-                          hw=TPU_V5E, max_model_len=128, max_slots=4)
+                          hw=TPU_V5E, max_model_len=128, max_slots=4,
+                          backend="ref")
         eng = LLMEngine(cfg, ex, num_blocks=128, block_size=8,
                         max_num_seqs=4, max_prefill_tokens=128,
                         max_model_len=128, enable_prefix_caching=caching)

@@ -1,5 +1,6 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode
-(the kernel body runs in Python on CPU; on TPU pass interpret=False)."""
+(the kernel body runs in Python on CPU). tests/test_tpu_compile.py compiles
+the same kernels for a described TPU v5e."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -22,8 +23,8 @@ from repro.kernels.paged_attention.ref import paged_attention_ref
 def test_paged_attention_sweep(s, h, kv, d, bs, mb, dtype, rng):
     nb = s * mb + 1
     q = jnp.asarray(rng.normal(size=(s, h, d)), dtype)
-    pk = jnp.asarray(rng.normal(size=(nb, bs, kv, d)), dtype)
-    pv = jnp.asarray(rng.normal(size=(nb, bs, kv, d)), dtype)
+    pk = jnp.asarray(rng.normal(size=(nb, kv, bs, d)), dtype)
+    pv = jnp.asarray(rng.normal(size=(nb, kv, bs, d)), dtype)
     bt = jnp.asarray(rng.integers(0, nb, size=(s, mb)), jnp.int32)
     lens = jnp.asarray(rng.integers(1, mb * bs + 1, size=(s,)), jnp.int32)
     ref = paged_attention_ref(q, pk, pv, bt, lens)
@@ -39,8 +40,8 @@ def test_paged_attention_single_token_context(rng):
     s, h, kv, d, bs, mb = 2, 4, 2, 64, 16, 4
     nb = 16
     q = jnp.asarray(rng.normal(size=(s, h, d)), jnp.float32)
-    pk = jnp.asarray(rng.normal(size=(nb, bs, kv, d)), jnp.float32)
-    pv = jnp.asarray(rng.normal(size=(nb, bs, kv, d)), jnp.float32)
+    pk = jnp.asarray(rng.normal(size=(nb, kv, bs, d)), jnp.float32)
+    pv = jnp.asarray(rng.normal(size=(nb, kv, bs, d)), jnp.float32)
     bt = jnp.asarray(rng.integers(0, nb, size=(s, mb)), jnp.int32)
     lens = jnp.ones((s,), jnp.int32)
     ref = paged_attention_ref(q, pk, pv, bt, lens)
@@ -48,7 +49,7 @@ def test_paged_attention_single_token_context(rng):
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     # with ctx=1, output must equal v at the first slot (softmax of one)
-    v0 = np.asarray(pv)[np.asarray(bt)[:, 0], 0]          # (S, KV, D)
+    v0 = np.asarray(pv)[np.asarray(bt)[:, 0], :, 0]       # (S, KV, D)
     v0 = np.repeat(v0, h // kv, axis=1)
     np.testing.assert_allclose(np.asarray(ref), v0, rtol=1e-5, atol=1e-5)
 

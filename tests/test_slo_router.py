@@ -358,7 +358,7 @@ def test_slo_cost_avoids_straggler_for_interactive():
                        services=ServiceConfig(routing_policy="slo_cost"))
     built = []
 
-    def factory(cfg, tp):
+    def factory(cfg, tp, gpu):
         hw = spec.hardware
         if len(built) % 2:
             hw = dataclasses.replace(

@@ -23,9 +23,10 @@ def test_full_stack_real_compute_end_to_end():
     cfg = configs.get("qwen3-1.7b").reduced()
     params, _ = api.init_params(cfg, jax.random.key(5))
 
-    def factory(c, tp):
+    def factory(c, tp, gpu):
         ex = RealExecutor(c, params, num_blocks=256, block_size=16,
-                          hw=TPU_V5E, max_model_len=256, max_slots=8)
+                          hw=TPU_V5E, max_model_len=256, max_slots=8,
+                          backend="ref")
         return LLMEngine(c, ex, num_blocks=256, block_size=16,
                          max_num_seqs=8, max_prefill_tokens=128,
                          max_model_len=256)

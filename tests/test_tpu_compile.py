@@ -1,0 +1,69 @@
+"""Compile the Pallas kernels of the served path for a described TPU v5e.
+
+Interpret mode (tests/test_kernels.py) cannot see Mosaic's tiling rules or
+its VMEM limit; the TPU compiler, installed here, compiles for a chip that
+is described and not attached. Widths are qwen3-1.7b's: 16 query heads,
+8 KV heads, head_dim 128, with the served pool (512 blocks, 1024-token
+sequences). Nothing runs, so nothing here says anything about results or
+time.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import configs
+from repro.kernels.flash_prefill.kernel import flash_prefill
+from repro.kernels.paged_attention.kernel import paged_attention
+
+CFG = configs.get("qwen3-1.7b")
+NUM_BLOCKS, MAX_MODEL_LEN, MAX_SEQS = 512, 1024, 8
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e:2x2 host, with the persistent cache
+    off: a compile for a described chip is written there but cannot be read
+    back without one."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or it cannot describe the chip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("block_size", [16, 32])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_compiles_for_v5e(one_chip, block_size, dtype):
+    h, kv, d = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim
+    pool = _sds((NUM_BLOCKS, kv, block_size, d), dtype, one_chip)
+    args = (_sds((MAX_SEQS, h, d), dtype, one_chip), pool, pool,
+            _sds((MAX_SEQS, MAX_MODEL_LEN // block_size), jnp.int32,
+                 one_chip),
+            _sds((MAX_SEQS,), jnp.int32, one_chip))
+    compiled = paged_attention.lower(*args, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_prefill_compiles_for_v5e(one_chip, dtype):
+    t = 2048
+    q = _sds((1, t, CFG.num_heads, CFG.head_dim), dtype, one_chip)
+    kv = _sds((1, t, CFG.num_kv_heads, CFG.head_dim), dtype, one_chip)
+    compiled = flash_prefill.lower(q, kv, kv, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()

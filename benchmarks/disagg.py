@@ -47,7 +47,7 @@ def build_plane(disaggregated: bool, total: int = 4, prefill: int = 2,
     the pool bring-up exactly as production would.  ``sanitize`` runs the
     plane on the TracingEventLoop (trace digest for determinism checks);
     ``services`` overrides the gateway `ServiceConfig` (e.g. tracing
-    knobs, benchmarks/trace_overhead.py)."""
+    knobs)."""
     # paper hardware, repo engine shape: the TPU-adapted static decode
     # batch (max_num_seqs=64, scheduler.py) is where decode residency
     # actually gates prompt admission — the contention disaggregation

@@ -145,6 +145,21 @@ class LeastLoaded(RoutingPolicy):
                 (self.load_fn(endpoint_key(ep)) or {})
                 .get("kv_utilization", 0.0), ep["id"])
 
+    def _observe(self, key: tuple) -> dict:
+        """The endpoint's latest scrape. A scrape not seen before already
+        reflects every earlier dispatch AND finish, so it restarts both
+        counts. Every dispatch and finish observes first: a scrape is then
+        seen before what follows it is counted, even while nothing reads
+        the depth (a finish counted into the old scrape's tally would be
+        lost with it, and the endpoint look loaded until the next scrape)."""
+        snap = self.load_fn(key) or {}
+        t = snap.get("time")
+        if t is not None and t != self._scrape_time.get(key):
+            self._scrape_time[key] = t
+            self._since_scrape[key] = 0
+            self._fin_since_scrape[key] = 0
+        return snap
+
     def effective_depth(self, ep: dict) -> int:
         """Scraped depth corrected by this gateway's own traffic since the
         scrape: dispatches add, finishes subtract — both directions, or a
@@ -152,19 +167,12 @@ class LeastLoaded(RoutingPolicy):
         look permanently loaded and the policy would herd onto slower ones
         (the exact effect the correction term exists to prevent)."""
         key = endpoint_key(ep)
-        snap = self.load_fn(key) or {}
+        snap = self._observe(key)
         scraped = snap.get("num_waiting", 0) + snap.get("num_running", 0)
-        t = snap.get("time")
-        if t is None:
+        if snap.get("time") is None:
             # never scraped: the gateway's own in-flight count is all we have
             pending = self._inflight.get(key, 0)
         else:
-            if t != self._scrape_time.get(key):
-                # new scrape observed: it already reflects earlier
-                # dispatches AND earlier finishes
-                self._scrape_time[key] = t
-                self._since_scrape[key] = 0
-                self._fin_since_scrape[key] = 0
             pending = self._since_scrape.get(key, 0) \
                 - self._fin_since_scrape.get(key, 0)
         return max(0, scraped + pending)
@@ -175,10 +183,12 @@ class LeastLoaded(RoutingPolicy):
     def note_dispatch(self, ep: dict, req: Request):
         super().note_dispatch(ep, req)
         key = endpoint_key(ep)
+        self._observe(key)
         self._inflight[key] = self._inflight.get(key, 0) + 1
         self._since_scrape[key] = self._since_scrape.get(key, 0) + 1
 
     def note_finish(self, ep_key: tuple, req: Request):
+        self._observe(ep_key)
         if self._inflight.get(ep_key, 0) > 0:
             self._inflight[ep_key] -= 1
         self._fin_since_scrape[ep_key] = \

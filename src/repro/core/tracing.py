@@ -33,20 +33,26 @@ Determinism: trace ids derive from `request_id`, sampling decisions from
 a keyed blake2b digest (`router._stable_hash`), and recording adds ZERO
 virtual time and schedules NOTHING on the EventLoop — twin sanitized
 runs produce bit-identical span forests (tests/test_determinism.py) and
-tracing on/off cannot move a single event (the <1 % overhead assertion
-of benchmarks/trace_overhead.py is exact by construction).
+tracing on/off cannot move a single event.
 
 Sampling is head-based but applied at RETENTION: the decision is a pure
 function of the trace id (plus `ServiceConfig` per-tenant overrides),
 never of the outcome — except that errors and SLO-misses are always
 retained (the traces an operator actually pages through).
+
+Host spans (`HOST_SPANS`, at the end of this module) are the other
+instrument: what the engine and executor spend on the host's clock,
+recorded only while started or while a JAX profile is captured, and
+written into that profile beside the device's operations.
 """
 from __future__ import annotations
 
 import hashlib
 import json
+import sys
+import time
 from collections import OrderedDict, deque
-from typing import Callable, Optional, Union
+from typing import Callable, NamedTuple, Optional, Union
 
 from repro.config import ServiceConfig
 from repro.core.router import _stable_hash
@@ -466,3 +472,171 @@ def _pct(sorted_vals: list, q: float) -> float:
     i = min(len(sorted_vals) - 1,
             max(0, int(round(q * (len(sorted_vals) - 1)))))
     return sorted_vals[i]
+
+
+# ---------------------------------------------------------------------------
+# host spans: the engine's and executor's own work on the host's clock
+# ---------------------------------------------------------------------------
+
+def host_clock() -> float:
+    """Seconds on the host's monotonic clock (the JAX profiler's too).
+
+    The one wall-clock read of the simulation packages: host spans and
+    `RealExecutor`'s measured step time read it, the event loop never
+    does, and a `SimExecutor` run never reaches it with spans off."""
+    # repro-lint: disable-next-line=R1(host spans and RealExecutor's measured step only; the event loop never reads this clock)
+    return time.perf_counter()
+
+
+def _profiling() -> bool:
+    """A JAX profile is being captured in this process."""
+    jax = sys.modules.get("jax")
+    return jax is not None and jax.profiler.TraceAnnotation.is_enabled()
+
+
+class HostSpan:
+    """One host span: `start`/`end` from `host_clock`, `parent` the id of
+    the span open around it (None at the top). It is also the context
+    manager that times it (`HostSpans.span`)."""
+
+    __slots__ = ("span_id", "parent", "name", "start", "end", "attrs",
+                 "_rec", "_note")
+
+    def __init__(self, rec: "HostSpans", span_id: int,
+                 parent: Optional[int], name: str, attrs: dict, note):
+        self.span_id = span_id
+        self.parent = parent
+        self.name = name
+        self.start = self.end = 0.0
+        self.attrs = attrs
+        self._rec, self._note = rec, note
+
+    @property
+    def duration(self) -> float:
+        return self.end - self.start
+
+    def set(self, **attrs):
+        """Attributes known only inside the span (a batch's size)."""
+        self.attrs.update(attrs)
+
+    def __enter__(self):
+        if self._note is not None:
+            self._note.__enter__()
+        self._rec._open.append(self.span_id)
+        self.start = host_clock()
+        return self
+
+    def __exit__(self, *exc):
+        self.end = host_clock()
+        rec, note = self._rec, self._note
+        self._rec = self._note = None
+        rec._open.pop()
+        rec._keep(rec.spans, self)
+        if note is not None:
+            note.__exit__(*exc)
+        return False
+
+    def __repr__(self):
+        return (f"HostSpan({self.name!r}, {self.duration * 1e3:.3f} ms, "
+                f"parent={self.parent})")
+
+
+class HostStamp(NamedTuple):
+    """A per-request event (`enqueue`, `admit`) at host time `t`."""
+    request_id: int
+    event: str
+    t: float
+    attrs: dict
+
+
+class _NoSpan:
+    """What `span` returns while nothing records: one shared object."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **attrs):
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+class HostSpans:
+    """Process-wide recorder of host spans and per-request stamps.
+
+    Off by default. It records while `start()`ed, or while a JAX profile
+    is being captured (the chip benchmark's traced run starts only the
+    profile around its window); with a profile each span is also a
+    ``repro.<name>`` `jax.profiler.TraceAnnotation`, on the host line of
+    the thread that ran it, on the device trace's clock. Off, `span`
+    returns one shared no-op and `stamp` returns at once. One thread
+    records (the event loop's): spans nest by the order they open.
+
+    Spans: ``engine.step`` / ``engine.schedule`` / ``engine.tokens``
+    (`LLMEngine`), ``executor.decode.dispatch`` /
+    ``executor.decode.fetch`` / ``executor.prefill`` (`RealExecutor`),
+    each with its ``replica`` (the executor's device id). Stamps:
+    ``enqueue`` and ``admit`` (`Scheduler`). Both buffers are bounded;
+    `dropped` counts what fell off their old ends."""
+
+    def __init__(self, capacity: int = 1 << 16):
+        self.started = False
+        self.spans: deque = deque(maxlen=capacity)
+        self.stamps: deque = deque(maxlen=capacity)
+        self.dropped = 0
+        self._open: list = []
+        self._next_id = 0
+
+    @property
+    def on(self) -> bool:
+        return self.started or _profiling()
+
+    def start(self):
+        self.started = True
+
+    def stop(self):
+        self.started = False
+
+    def drain(self) -> tuple:
+        """(spans, stamps) recorded so far, oldest first; both cleared."""
+        out = (list(self.spans), list(self.stamps))
+        self.spans.clear()
+        self.stamps.clear()
+        self.dropped = 0
+        return out
+
+    def span(self, name: str, **attrs):
+        """Context manager timing its block as host span `name`; `.set()`
+        adds attributes inside it."""
+        profiling = _profiling()
+        if not (profiling or self.started):
+            return _NO_SPAN
+        note = None
+        if profiling:
+            note = sys.modules["jax"].profiler.TraceAnnotation(
+                "repro." + name)
+        span = HostSpan(self, self._next_id,
+                        self._open[-1] if self._open else None, name, attrs,
+                        note)
+        self._next_id += 1
+        return span
+
+    def stamp(self, request_id: int, event: str, **attrs):
+        if self.on:
+            self._keep(self.stamps,
+                       HostStamp(request_id, event, host_clock(), attrs))
+
+    def _keep(self, buf: deque, item):
+        if len(buf) == buf.maxlen:
+            self.dropped += 1
+        buf.append(item)
+
+
+#: the process's recorder (the JAX profiler it feeds is process-wide too)
+HOST_SPANS = HostSpans()

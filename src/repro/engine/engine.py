@@ -13,6 +13,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.core.tracing import HOST_SPANS
 from repro.engine.kv_cache import BlockAllocator, HandoffBlockSizeMismatch, \
     export_handoff, import_handoff
 from repro.engine.metrics import EngineMetrics, snapshot
@@ -36,12 +37,15 @@ class LLMEngine:
                  phase_mode: str = "unified"):
         self.cfg = cfg
         self.executor = executor
+        # the executor's device id (None for SimExecutor): host spans carry it
+        self.replica = getattr(executor, "replica", None)
         self.allocator = BlockAllocator(
             num_blocks, block_size, enable_prefix_caching=enable_prefix_caching)
         self.scheduler = Scheduler(self.allocator, max_num_seqs=max_num_seqs,
                                    max_prefill_tokens=max_prefill_tokens,
                                    max_model_len=max_model_len,
-                                   phase_mode=phase_mode)
+                                   phase_mode=phase_mode,
+                                   replica=self.replica)
         self.phase_mode = phase_mode
         # disaggregation hook: fn(req, KVHandoff, now) fired by a
         # prefill-only engine once a request's first token is out and its
@@ -140,7 +144,13 @@ class LLMEngine:
 
     # ------------------------------------------------------------------
     def step(self, now: float) -> StepReport:
-        out = self.scheduler.schedule(now)
+        with HOST_SPANS.span("engine.step", replica=self.replica):
+            return self._step(now)
+
+    def _step(self, now: float) -> StepReport:
+        with HOST_SPANS.span("engine.schedule", replica=self.replica) as sp:
+            out = self.scheduler.schedule(now)
+            sp.set(decode_rows=len(out.decode), prefills=len(out.prefills))
         self.metrics.preemptions += len(out.preempted)
         if out.kind == "idle":
             return StepReport("idle", 0.0)
@@ -170,29 +180,34 @@ class LLMEngine:
         finished = 0
         tokens = 0
 
-        if out.decode:
-            for i, s in enumerate(out.decode):
-                row = None if dec_logits is None else dec_logits[i]
-                finished += int(self._emit(s, self._sample(s.req, row),
-                                           t_done))
-            self.metrics.tokens_generated += len(out.decode)
-            tokens += len(out.decode)
+        with HOST_SPANS.span("engine.tokens", replica=self.replica) as sp:
+            if out.decode:
+                for i, s in enumerate(out.decode):
+                    row = None if dec_logits is None else dec_logits[i]
+                    finished += int(self._emit(s, self._sample(s.req, row),
+                                               t_done))
+                self.metrics.tokens_generated += len(out.decode)
+                tokens += len(out.decode)
 
-        for i, (seq, (start, end)) in enumerate(out.prefills):
-            self.metrics.tokens_prefilled += end - start
-            tokens += end - start
-            if seq.prompt_done and not seq.req.output_tokens:
-                row = pre_logits[i] if pre_logits else None
-                tok = self._sample(seq.req, row)
-                done = self._emit(seq, tok, t_done)
-                finished += int(done)
-                if not done and self.phase_mode == "prefill_only":
-                    # first token is out; hand the sealed prompt KV to the
-                    # decode pool instead of decoding here
-                    self._export_handoff(seq, t_done)
-            # a resumed decode hop reaching prompt_done (tail recompute)
-            # already carries its first token — no sample, no handoff; the
-            # next step decodes it like any running sequence
+            sampled = len(out.decode)
+            for i, (seq, (start, end)) in enumerate(out.prefills):
+                self.metrics.tokens_prefilled += end - start
+                tokens += end - start
+                if seq.prompt_done and not seq.req.output_tokens:
+                    row = pre_logits[i] if pre_logits else None
+                    tok = self._sample(seq.req, row)
+                    sampled += 1
+                    done = self._emit(seq, tok, t_done)
+                    finished += int(done)
+                    if not done and self.phase_mode == "prefill_only":
+                        # first token is out; hand the sealed prompt KV to
+                        # the decode pool instead of decoding here
+                        self._export_handoff(seq, t_done)
+                # a resumed decode hop reaching prompt_done (tail
+                # recompute) already carries its first token — no sample,
+                # no handoff; the next step decodes it like any running
+                # sequence
+            sp.set(tokens=sampled)
 
         return StepReport("mixed", elapsed, tokens=tokens, finished=finished)
 

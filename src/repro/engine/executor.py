@@ -11,7 +11,9 @@ SimExecutor    — no compute; the roofline cost model supplies step times and
                  virtual-clock benchmarks (50 runs × 1000 concurrency would
                  be absurd to run with real compute on CPU).
 
-Both return (logits | None, elapsed_seconds) so the engine is agnostic.
+Both return (logits | None, elapsed_seconds) so the engine is agnostic:
+RealExecutor's elapsed is the host time its call took (`host_clock`), the
+device's time included, SimExecutor's the roofline estimate.
 """
 from __future__ import annotations
 
@@ -20,6 +22,7 @@ from typing import Optional
 import numpy as np
 
 from repro.config import HardwareConfig, ModelConfig
+from repro.core.tracing import HOST_SPANS, host_clock
 from repro.engine.costmodel import RooflineCost
 
 try:  # jax only needed for RealExecutor
@@ -76,6 +79,7 @@ class RealExecutor:
                     f"{self.device.platform!r}; pass backend='ref' or "
                     f"backend='interpret'")
             backend = "pallas"
+        self.replica = self.device.id
         self.cfg = cfg
         self.params = params
         self.block_size = block_size
@@ -83,6 +87,14 @@ class RealExecutor:
         self.backend = backend
         self.cost = RooflineCost(cfg, hw, tp=tp)
         self.api = api
+
+        def prefill(params, batch):
+            return api.prefill_fn(params, cfg, batch)
+
+        # one program per prompt length, traced and compiled on its first
+        # call: run eagerly, the layer scan is traced, lowered and compiled
+        # again on every prompt
+        self._prefill_program = jax.jit(prefill)
         self.paged = cfg.family in ("dense", "vlm", "moe")
         self.max_model_len = max_model_len
         self.max_slots = max_slots
@@ -107,43 +119,51 @@ class RealExecutor:
     # ------------------------------------------------------------------
     def step(self, prefills: list, decode: Optional[dict]):
         """Mixed step: decode batch first (pre-step KV state), then the
-        prefill chunks. One combined cost-model time (weights stream once)."""
-        new_tokens = ctx = 0
-        for pf in prefills or ():
-            start, end = pf["chunk"]
-            new_tokens += end - start
-            ctx += end
-        batch = total_ctx = 0
-        if decode is not None:
-            batch = len(decode["slots"])
-            total_ctx = int(sum(p + 1 for p in decode["pos"]))
-        elapsed = self.cost.mixed_time(new_tokens, ctx, batch, total_ctx)
-
+        prefill chunks. Elapsed is the host time of the call, which ends
+        with every logit on the host, so it includes the device's."""
+        t0 = host_clock()
         dec_logits = self._decode(decode) if decode else None
         pre_logits = [self._prefill(pf) for pf in prefills or ()]
-        return pre_logits, dec_logits, elapsed
+        return pre_logits, dec_logits, host_clock() - t0
 
     def _prefill(self, pf: dict):
         if not pf["is_last"]:
-            # chunked prefill: timing per chunk; compute happens once on the
-            # final chunk (whole-prompt recompute — numerically identical)
+            # chunked prefill: the whole prompt is computed once, on its
+            # final chunk (numerically identical), which bears its time
             return None
-        toks = self._put(pf["token_ids"])[None]
-        logits, cache = self.api.prefill_fn(self.params, self.cfg,
-                                            {"tokens": toks})
-        if self.paged:
-            bt = self._put(pf["block_table"])
-            self.pool = self._paged_model.write_prefill(
-                self.pool, cache, bt, self.block_size)
-        else:
-            cache = self.api.pad_cache(self.cfg, cache, self.max_model_len)
-            slot = pf["slot"]
-            self.cache = jax.tree.map(
-                lambda slab, c: slab.at[:, slot].set(c[:, 0].astype(slab.dtype)),
-                self.cache, cache)
-        return np.asarray(logits[0])
+        with HOST_SPANS.span("executor.prefill", replica=self.replica,
+                             tokens=len(pf["token_ids"])):
+            toks = self._put(pf["token_ids"])[None]
+            logits, cache = self._prefill_program(self.params,
+                                                  {"tokens": toks})
+            if self.paged:
+                bt = self._put(pf["block_table"])
+                self.pool = self._paged_model.write_prefill(
+                    self.pool, cache, bt, self.block_size)
+            else:
+                cache = self.api.pad_cache(self.cfg, cache,
+                                           self.max_model_len)
+                slot = pf["slot"]
+                self.cache = jax.tree.map(
+                    lambda slab, c: slab.at[:, slot].set(
+                        c[:, 0].astype(slab.dtype)),
+                    self.cache, cache)
+            return np.asarray(logits[0])
 
     def _decode(self, dec: dict):
+        """Logits of the batch's rows on the host. Two host spans: the
+        dispatch (inputs built and put, the step traced, lowered or
+        fetched, and enqueued) and the fetch (waiting for the device, then
+        the copy)."""
+        n = len(dec["slots"])
+        with HOST_SPANS.span("executor.decode.dispatch",
+                             replica=self.replica, rows=n):
+            logits = self._dispatch_decode(dec)
+        with HOST_SPANS.span("executor.decode.fetch", replica=self.replica,
+                             rows=n):
+            return np.asarray(logits[:n])
+
+    def _dispatch_decode(self, dec: dict):
         slots, tokens, pos = dec["slots"], dec["tokens"], dec["pos"]
         if self.paged:
             # The batch is always max_slots rows, so a sequence's logits
@@ -162,7 +182,7 @@ class RealExecutor:
                 self.params, self.cfg, self._put(list(tokens) + pad),
                 self._put(list(pos) + pad), self.pool, self._put(bt),
                 backend=self.backend)
-            return np.asarray(logits[:n])
+            return logits
         toks = self._put(tokens)
         posv = self._put(pos)
         # state executor: gather slot caches, run decode_fn, scatter back
@@ -173,4 +193,4 @@ class RealExecutor:
         self.cache = jax.tree.map(
             lambda slab, c: slab.at[:, sl].set(c.astype(slab.dtype)),
             self.cache, cache)
-        return np.asarray(logits)
+        return logits

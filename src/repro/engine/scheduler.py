@@ -18,6 +18,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Optional
 
+from repro.core.tracing import HOST_SPANS
 from repro.engine.kv_cache import BlockAllocator, OutOfBlocks, SequenceKV
 from repro.engine.request import Request, RequestStatus
 
@@ -52,9 +53,11 @@ PHASE_MODES = ("unified", "prefill_only", "decode_only")
 class Scheduler:
     def __init__(self, allocator: BlockAllocator, max_num_seqs: int = 64,
                  max_prefill_tokens: int = 2048, max_model_len: int = 8192,
-                 phase_mode: str = "unified"):
+                 phase_mode: str = "unified",
+                 replica: Optional[int] = None):
         assert phase_mode in PHASE_MODES, phase_mode
         self.alloc = allocator
+        self.replica = replica        # the executor's device id, for stamps
         self.max_num_seqs = max_num_seqs
         self.max_prefill_tokens = max_prefill_tokens
         self.max_model_len = max_model_len
@@ -74,6 +77,7 @@ class Scheduler:
         if req.handoff is None and not req.output_tokens:
             req.metrics.arrival_time = now
         req.metrics.last_enqueue_time = now
+        HOST_SPANS.stamp(req.request_id, "enqueue", replica=self.replica)
         req.status = RequestStatus.WAITING
         if req.trace is not None:
             # one engine.queue span per hop (the decode hop of a
@@ -126,6 +130,8 @@ class Scheduler:
         if req.metrics.first_scheduled_time is None:
             req.metrics.first_scheduled_time = now
         req.metrics.last_scheduled_time = now
+        if req.status is RequestStatus.WAITING:   # not a preempted re-admit
+            HOST_SPANS.stamp(req.request_id, "admit", replica=self.replica)
         req.status = RequestStatus.RUNNING
         if req.trace is not None:
             req.trace.close_span("engine.queue", now)

@@ -121,6 +121,7 @@ def flash_prefill(q, k, v, window: int = 0, bq: int = 128, bk: int = 512,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_prefill",      # the op's name in the device trace
     )(qg, kg, vg)
 
     return out.reshape(b, kvh, t, qpk, d).transpose(0, 2, 1, 3, 4) \

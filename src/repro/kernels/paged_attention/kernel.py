@@ -115,5 +115,6 @@ def paged_attention(q, pool_k, pool_v, block_tables, context_lens,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention",      # the op's name in the device trace
     )(block_tables, context_lens, qg, pool_k, pool_v)
     return out.reshape(s, h, d)

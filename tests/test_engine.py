@@ -231,3 +231,117 @@ def test_engine_metrics_snapshot():
     assert snap["requests_finished_total"] == 3
     assert snap["tokens_generated_total"] >= 3 * 7
     assert snap["kv_utilization"] >= 0.0
+
+
+@pytest.fixture
+def host_spans():
+    """The process's host-span recorder, started for one test."""
+    from repro.core.tracing import HOST_SPANS
+    HOST_SPANS.drain()
+    HOST_SPANS.start()
+    yield HOST_SPANS
+    HOST_SPANS.stop()
+    HOST_SPANS.drain()
+
+
+def test_stamps_give_the_wait_behind_a_full_batch(host_spans):
+    cfg = configs.get("mistral-small-24b")
+    from repro.config import GPU_H100
+    eng = LLMEngine(cfg, SimExecutor(cfg, GPU_H100), num_blocks=64,
+                    block_size=16, max_num_seqs=1, max_prefill_tokens=256,
+                    max_model_len=512)
+    first, second = [Request(prompt_tokens=[i + 1] * 32,
+                             sampling=SamplingParams(target_output_len=4,
+                                                     max_new_tokens=4))
+                     for i in range(2)]
+    eng.add_request(first, 0.0)
+    eng.add_request(second, 0.0)
+    run_engine(eng, [])
+    spans, stamps = host_spans.drain()
+    at = {(s.request_id, s.event): s.t for s in stamps}
+    assert sorted(at) == sorted((r.request_id, e) for r in (first, second)
+                                for e in ("enqueue", "admit"))
+    wait = {r.request_id: at[r.request_id, "admit"]
+            - at[r.request_id, "enqueue"] for r in (first, second)}
+    # the second waits for the one row through every call that served
+    # the first, and was admitted in the call after the first finished
+    steps = [s for s in spans if s.name == "engine.step"]
+    before = [s for s in steps if s.end <= at[second.request_id, "admit"]]
+    assert len(before) == 4             # prefill + 3 decodes of the first
+    assert wait[second.request_id] >= sum(s.duration for s in before)
+    assert wait[first.request_id] < wait[second.request_id]
+    assert {s.attrs["replica"] for s in spans} == {None}   # no device
+    sched = [s for s in spans if s.name == "engine.schedule"]
+    assert [s.attrs["decode_rows"] for s in sched[:5]] == [0, 1, 1, 1, 0]
+    assert all(s.parent in {t.span_id for t in steps} for s in sched)
+
+
+def test_real_executor_reports_its_measured_time(dense_setup, rng,
+                                                 host_spans):
+    cfg, params = dense_setup
+    ex = RealExecutor(cfg, params, num_blocks=64, block_size=16, hw=TPU_V5E,
+                      max_model_len=256, max_slots=4, backend="ref")
+    eng = LLMEngine(cfg, ex, num_blocks=64, block_size=16, max_num_seqs=4,
+                    max_prefill_tokens=64, max_model_len=256)
+    calls = []
+    inner = ex.step
+
+    def timed(prefills, decode):
+        from repro.core.tracing import host_clock
+        t0 = host_clock()
+        out = inner(prefills, decode)
+        calls.append((t0, host_clock(), out[2], prefills, decode))
+        return out
+
+    ex.step = timed
+    reqs = [Request(prompt_tokens=list(rng.integers(1, cfg.vocab_size,
+                                                    size=n)),
+                    sampling=SamplingParams(temperature=0.0,
+                                            max_new_tokens=5))
+            for n in (9, 20)]
+    run_engine(eng, reqs)
+    spans, _ = host_spans.drain()
+    assert all(r.status.value == "finished" for r in reqs)
+    own = {}
+    for s in spans:
+        if s.name.startswith("executor."):
+            own.setdefault(s.parent, []).append(s)
+    steps = [s for s in spans if s.name == "engine.step"
+             and s.span_id in own]
+    assert len(steps) == len(calls)
+    for (t0, t1, elapsed, _, _), step in zip(calls, steps):
+        # the call's own host time: inside the wrapper's, over its spans
+        assert 0 < elapsed <= t1 - t0
+        assert elapsed >= sum(s.duration for s in own[step.span_id])
+        assert {s.attrs["replica"] for s in own[step.span_id]} == {
+            ex.device.id}
+    names = [s.name for s in spans]
+    assert names.count("executor.prefill") == 2
+    assert names.count("executor.decode.dispatch") == \
+        names.count("executor.decode.fetch") == 4
+    assert eng.metrics.busy_time == pytest.approx(sum(c[2] for c in calls))
+    # the roofline estimate stays SimExecutor's elapsed
+    sim = SimExecutor(cfg, TPU_V5E)
+    _, _, _, prefills, decode = calls[1]
+    _, _, est = sim.step(prefills, decode)
+    assert est == pytest.approx(sim.cost.mixed_time(
+        0, 0, len(decode["slots"]), sum(p + 1 for p in decode["pos"])))
+
+
+def test_real_executor_compiles_each_prompt_length_once(dense_setup, rng):
+    """The prefill is one program per prompt length: a second prompt of a
+    length served before runs it again, and is neither traced nor
+    compiled anew."""
+    cfg, params = dense_setup
+    ex = RealExecutor(cfg, params, num_blocks=64, block_size=16, hw=TPU_V5E,
+                      max_model_len=256, max_slots=4, backend="ref")
+    eng = LLMEngine(cfg, ex, num_blocks=64, block_size=16, max_num_seqs=4,
+                    max_prefill_tokens=64, max_model_len=256)
+    reqs = [Request(prompt_tokens=list(rng.integers(1, cfg.vocab_size,
+                                                    size=n)),
+                    sampling=SamplingParams(temperature=0.0,
+                                            max_new_tokens=3))
+            for n in (9, 20, 9, 20, 9)]
+    run_engine(eng, reqs)
+    assert all(r.status.value == "finished" for r in reqs)
+    assert ex._prefill_program._cache_size() == 2

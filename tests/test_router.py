@@ -582,6 +582,33 @@ def test_least_loaded_finish_between_scrapes_decrements():
     assert pol.effective_depth(rows[0]) == 0
 
 
+def test_least_loaded_counts_finishes_after_a_scrape_nothing_read():
+    """A scrape taken while an endpoint was busy, then the endpoint's
+    finishes while nothing read its depth (another endpoint pinned, say):
+    the finishes belong to that scrape's tally, not to the old one's, or
+    the endpoint looks loaded until the next scrape and a burst herds onto
+    the other endpoint."""
+    load = {k: {"time": 5.0, "num_waiting": 0, "num_running": 0,
+                "kv_utilization": 0.0}
+            for k in [("node000", 8000), ("node001", 8000)]}
+    pol = LeastLoaded(load_fn=lambda k: load.get(k, {}))
+    rows = eps(2)
+    for _ in range(4):
+        pol.note_dispatch(rows[1], req())
+    load[("node001", 8000)] = {"time": 10.0, "num_waiting": 0,
+                               "num_running": 4, "kv_utilization": 0.0}
+    for _ in range(4):
+        pol.note_finish(("node001", 8000), req())
+    assert pol.effective_depth(rows[1]) == 0    # was 4 pre-fix
+    assert pol.effective_depth(rows[0]) == 0
+    assert pol.select(rows, req())["id"] == 1
+    # dispatches after an unread scrape count against it the same way
+    load[("node000", 8000)] = {"time": 15.0, "num_waiting": 0,
+                               "num_running": 0, "kv_utilization": 0.0}
+    pol.note_dispatch(rows[0], req())
+    assert pol.effective_depth(rows[0]) == 1
+
+
 def test_zombie_endpoint_no_double_select_round_robin():
     """A zombie endpoint row (instance died, row still READY) must be
     filtered BEFORE the policy runs: the old select-then-retry path

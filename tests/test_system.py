@@ -69,7 +69,13 @@ def test_full_stack_real_compute_end_to_end():
         status = cp.web_gateway.handle("sk-e2e", cfg.name, r)
         assert status == 200
         reqs.append(r)
-    cp.run_until(cp.loop.now + 120.0)
+    # the served steps advance the virtual clock by their measured host
+    # time: serve until every request ends, against a deadline far past
+    # any CPU's compute for three short requests, so a stalled request
+    # still ends the loop (the periodic tasks reach it) and fails below
+    cp.loop.run_while(
+        lambda: not all(r.status.value in ("finished", "failed")
+                        for r in reqs), max_t=cp.loop.now + 3600.0)
 
     for r, exp in zip(reqs, expected):
         assert r.status.value == "finished"

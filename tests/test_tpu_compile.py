@@ -67,3 +67,29 @@ def test_flash_prefill_compiles_for_v5e(one_chip, dtype):
     kv = _sds((1, t, CFG.num_kv_heads, CFG.head_dim), dtype, one_chip)
     compiled = flash_prefill.lower(q, kv, kv, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_kernel_names_survive_an_enclosing_program(one_chip):
+    """The device trace keys a kernel's time by its HLO name, and
+    `paged_attn_roofline` reads `…:paged_attention`: the name comes from
+    the kernel's own `name=`, so it holds when a jitted step inlines the
+    kernel's body (without `name=` XLA calls it `closed_call`)."""
+    import re
+    bs, h, kv, d = 16, CFG.num_heads, CFG.num_kv_heads, CFG.head_dim
+    pool = _sds((NUM_BLOCKS, kv, bs, d), jnp.float32, one_chip)
+    args = (_sds((MAX_SEQS, h, d), jnp.float32, one_chip), pool, pool,
+            _sds((MAX_SEQS, MAX_MODEL_LEN // bs), jnp.int32, one_chip),
+            _sds((MAX_SEQS,), jnp.int32, one_chip))
+
+    def step(q, pk, pv, bt, lens):
+        return paged_attention.__wrapped__(q, pk, pv, bt, lens,
+                                           interpret=False) * 2.0
+
+    text = jax.jit(step).lower(*args).compile().as_text()
+    assert re.search(r"%paged_attention(\.\d+)? = \S+ custom-call", text)
+    t = 512
+    q = _sds((1, t, h, d), jnp.bfloat16, one_chip)
+    k = _sds((1, t, kv, d), jnp.bfloat16, one_chip)
+    text = jax.jit(lambda q, k, v: flash_prefill.__wrapped__(
+        q, k, v, interpret=False) + 1).lower(q, k, k).compile().as_text()
+    assert re.search(r"%flash_prefill(\.\d+)? = \S+ custom-call", text)

@@ -13,8 +13,9 @@ from repro.config import SLOTarget, ServiceConfig
 from repro.core.controller import ClusterSpec, ControlPlane
 from repro.core.deployments import ModelDeploymentSpec
 from repro.core.disagg import DisaggregationSpec
-from repro.core.tracing import (COMPUTE_KINDS, RequestTrace, SPAN_KINDS,
-                                Tracer, critical_path, head_sampled)
+from repro.core.tracing import (COMPUTE_KINDS, HostSpans, RequestTrace,
+                                SPAN_KINDS, Tracer, critical_path,
+                                head_sampled, host_clock)
 
 MODEL = "smollm-135m"
 
@@ -459,3 +460,64 @@ def test_tracing_disabled_plane_serves_identically_with_no_traces():
     assert req.trace is None
     assert cp.tracer.stats()["started"] == 0
     assert len(cp.tracer.traces) == 0
+
+
+# ---------------------------------------------------------------------------
+# unit: host spans (the host-clock recorder)
+# ---------------------------------------------------------------------------
+
+def test_host_spans_off_return_the_shared_noop_and_record_nothing():
+    rec = HostSpans()
+    a = rec.span("engine.step", replica=0)
+    b = rec.span("executor.prefill", replica=0, tokens=9)
+    assert a is b                       # one shared object, nothing made
+    with a as sp:
+        sp.set(rows=8)
+    rec.stamp(1, "enqueue", replica=0)
+    assert not rec.on
+    assert rec.drain() == ([], [])
+
+
+def test_host_spans_nest_and_record_their_parent():
+    rec = HostSpans()
+    rec.start()
+    t0 = host_clock()
+    with rec.span("engine.step", replica=3) as outer:
+        with rec.span("executor.decode.dispatch", replica=3) as inner:
+            inner.set(rows=8)
+        with rec.span("engine.tokens", replica=3):
+            rec.stamp(7, "admit", replica=3)
+        outer.set(kind="mixed")
+    t1 = host_clock()
+    spans, stamps = rec.drain()
+    by = {s.name: s for s in spans}
+    step = by["engine.step"]
+    assert [s.name for s in spans] == ["executor.decode.dispatch",
+                                       "engine.tokens", "engine.step"]
+    assert step.parent is None
+    assert by["executor.decode.dispatch"].parent == step.span_id
+    assert by["engine.tokens"].parent == step.span_id
+    assert by["executor.decode.dispatch"].attrs == {"replica": 3, "rows": 8}
+    assert step.attrs == {"replica": 3, "kind": "mixed"}
+    assert t0 <= step.start <= by["engine.tokens"].start
+    assert by["engine.tokens"].end <= step.end <= t1
+    assert sum(s.duration for s in spans[:2]) <= step.duration
+    (st,) = stamps
+    assert st.request_id == 7 and st.event == "admit"
+    assert by["engine.tokens"].start <= st.t <= by["engine.tokens"].end
+    rec.stop()
+    assert rec.span("engine.step") is rec.span("engine.tokens")
+
+
+def test_host_spans_drain_clears_and_the_buffers_are_bounded():
+    rec = HostSpans(capacity=4)
+    rec.start()
+    for i in range(6):
+        with rec.span("engine.step", i=i):
+            pass
+        rec.stamp(i, "enqueue")
+    assert rec.dropped == 4             # two spans and two stamps fell off
+    spans, stamps = rec.drain()
+    assert [s.attrs["i"] for s in spans] == [2, 3, 4, 5]
+    assert [s.request_id for s in stamps] == [2, 3, 4, 5]
+    assert rec.drain() == ([], []) and rec.dropped == 0

@@ -160,19 +160,17 @@ def check_kernel(cfg, ex, seed):
         raise RuntimeError(f"paged attention {backend} vs ref: {err}")
 
 
-def check_decode_program(cfg, ex):
-    """Lower the served decode step with the replica's own arrays and look
+def check_decode_program(ex):
+    """Lower the replica's own decode program with its own arrays and look
     for the Mosaic kernel in it."""
     import jax
     import numpy as np
-    from repro.engine import paged_model
 
     s = ex.max_slots
     ids = jax.device_put(np.zeros((s,), np.int32), ex.device)
     bt = jax.device_put(np.zeros((s, ex.mb), np.int32), ex.device)
-    step = jax.jit(paged_model.decode_step, static_argnames=("cfg", "backend"))
-    text = step.lower(ex.params, cfg, ids, ids, ex.pool, bt,
-                      backend=ex.backend).as_text()
+    text = ex._decode_program.lower(ex.params, ids, ids, ex.pool,
+                                    bt).as_text()
     found = "tpu_custom_call" in text
     log(f"decode backend: {ex.backend}; tpu_custom_call in the lowered "
         f"decode step: {found}")
@@ -232,7 +230,7 @@ def one_chip(args, cfg, devices, hw, backend, prompts):
         f"{tree_bytes(ex.pool)} B "
         f"{tuple(ex.pool['k'].shape)} {ex.pool['k'].dtype}")
     check_kernel(cfg, ex, args.seed)
-    check_decode_program(cfg, ex)
+    check_decode_program(ex)
     check_logits(cfg, ex.params, taps[0].rows, ex.max_model_len)
     cp.shutdown()
     peak = (dev.memory_stats() or {}).get("peak_bytes_in_use", "not reported")

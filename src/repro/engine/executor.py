@@ -106,6 +106,17 @@ class RealExecutor:
                                               block_size, device=self.device)
             self._paged_model = paged_model
             self.mb = -(-max_model_len // block_size)
+
+            def decode_step(params, tokens, pos, pool, block_tables):
+                return paged_model.decode_step(params, cfg, tokens, pos,
+                                               pool, block_tables,
+                                               backend=backend)
+
+            # one program: the batch is padded to max_slots rows and the
+            # tables to mb pages, so the step is traced and compiled once,
+            # on its first call. The pool is donated: the new pool comes
+            # back in the old one's buffer, and no second pool outlives it.
+            self._decode_program = jax.jit(decode_step, donate_argnums=(3,))
         else:
             # state executor: one dense/state cache slab over all slots
             with jax.default_device(self.device):
@@ -152,16 +163,18 @@ class RealExecutor:
 
     def _decode(self, dec: dict):
         """Logits of the batch's rows on the host. Two host spans: the
-        dispatch (inputs built and put, the step traced, lowered or
-        fetched, and enqueued) and the fetch (waiting for the device, then
-        the copy)."""
+        dispatch (inputs built and put, and the compiled step enqueued; the
+        first call traces and compiles it) and the fetch (waiting for the
+        device, then the copy)."""
         n = len(dec["slots"])
         with HOST_SPANS.span("executor.decode.dispatch",
                              replica=self.replica, rows=n):
             logits = self._dispatch_decode(dec)
         with HOST_SPANS.span("executor.decode.fetch", replica=self.replica,
                              rows=n):
-            return np.asarray(logits[:n])
+            # cut on the host: a slice on the device is one more program
+            # for each batch size, lowered the first time the size comes
+            return np.asarray(logits)[:n]
 
     def _dispatch_decode(self, dec: dict):
         slots, tokens, pos = dec["slots"], dec["tokens"], dec["pos"]
@@ -178,10 +191,9 @@ class RealExecutor:
             for i, table in enumerate(dec["block_tables"]):
                 bt[i, :len(table)] = table
             pad = [0] * (self.max_slots - n)
-            logits, self.pool = self._paged_model.decode_step(
-                self.params, self.cfg, self._put(list(tokens) + pad),
-                self._put(list(pos) + pad), self.pool, self._put(bt),
-                backend=self.backend)
+            logits, self.pool = self._decode_program(
+                self.params, self._put(list(tokens) + pad),
+                self._put(list(pos) + pad), self.pool, self._put(bt))
             return logits
         toks = self._put(tokens)
         posv = self._put(pos)

@@ -345,3 +345,97 @@ def test_real_executor_compiles_each_prompt_length_once(dense_setup, rng):
     run_engine(eng, reqs)
     assert all(r.status.value == "finished" for r in reqs)
     assert ex._prefill_program._cache_size() == 2
+
+
+def test_decode_step_traced_once_across_batch_sizes(dense_setup, rng,
+                                                    monkeypatch):
+    """The decode step is one jitted program per executor: batches of 1, 2
+    and 4 live rows at many positions run it without tracing it again, and
+    the greedy tokens stay the dense oracle's."""
+    from repro.engine import paged_model
+    cfg, params = dense_setup
+    traces = []
+    decode_step = paged_model.decode_step
+
+    def spy(*args, **kw):
+        traces.append(1)
+        return decode_step(*args, **kw)
+
+    monkeypatch.setattr(paged_model, "decode_step", spy)
+    lens = (7, 13, 21, 30)
+    new = (12, 9, 4, 4)
+    prompts = [list(rng.integers(1, cfg.vocab_size, size=n)) for n in lens]
+    oracle = [oracle_generate(cfg, params, p, k)
+              for p, k in zip(prompts, new)]
+    ex = RealExecutor(cfg, params, num_blocks=64, block_size=8, hw=TPU_V5E,
+                      max_model_len=64, max_slots=4, backend="ref")
+    eng = LLMEngine(cfg, ex, num_blocks=64, block_size=8, max_num_seqs=4,
+                    max_prefill_tokens=128, max_model_len=64)
+    rows = []
+    inner = ex.step
+
+    def counted(prefills, decode):
+        if decode:
+            rows.append(len(decode["slots"]))
+        return inner(prefills, decode)
+
+    ex.step = counted
+    reqs = [Request(prompt_tokens=p,
+                    sampling=SamplingParams(temperature=0.0,
+                                            max_new_tokens=k))
+            for p, k in zip(prompts, new)]
+    # one row alone, then a second joins, then all four
+    now = 0.0
+    for batch, steps in ((reqs[:1], 3), (reqs[1:2], 3), (reqs[2:], 100)):
+        for r in batch:
+            eng.add_request(r, now)
+        for _ in range(steps):
+            if not eng.has_work():
+                break
+            now += max(eng.step(now).elapsed, 1e-4)
+    assert not eng.has_work()
+    assert {1, 2, 4} <= set(rows)
+    assert len(traces) == 1
+    assert ex._decode_program._cache_size() == 1
+    for r, o in zip(reqs, oracle):
+        assert r.status.value == "finished"
+        assert r.output_tokens == o
+
+
+def test_decode_donates_the_pool(dense_setup, rng):
+    """Each decode call consumes the pool it was given; prefills written
+    into the pool a decode returned, and decoded after, still give the
+    dense oracle's tokens, so no donated pool is read again."""
+    cfg, params = dense_setup
+    prompts = [list(rng.integers(1, cfg.vocab_size, size=n))
+               for n in (10, 26)]
+    oracle = [oracle_generate(cfg, params, p, 6) for p in prompts]
+    ex = RealExecutor(cfg, params, num_blocks=32, block_size=8, hw=TPU_V5E,
+                      max_model_len=64, max_slots=4, backend="ref")
+    eng = LLMEngine(cfg, ex, num_blocks=32, block_size=8, max_num_seqs=4,
+                    max_prefill_tokens=64, max_model_len=64)
+    given = []
+    program = ex._decode_program
+
+    def held(params, tokens, pos, pool, block_tables):
+        given.append(pool)
+        return program(params, tokens, pos, pool, block_tables)
+
+    ex._decode_program = held
+    reqs = [Request(prompt_tokens=p,
+                    sampling=SamplingParams(temperature=0.0,
+                                            max_new_tokens=6))
+            for p in prompts]
+    # the second prompt is written into a pool that decodes have returned
+    eng.add_request(reqs[0], 0.0)
+    now = 0.0
+    for _ in range(3):
+        now += max(eng.step(now).elapsed, 1e-4)
+    assert len(given) == 2 and not reqs[1].output_tokens
+    run_engine(eng, reqs[1:])
+    assert len(given) > 2
+    assert all(x.is_deleted() for pool in given for x in pool.values())
+    assert not any(x.is_deleted() for x in ex.pool.values())
+    for r, o in zip(reqs, oracle):
+        assert r.status.value == "finished"
+        assert r.output_tokens == o

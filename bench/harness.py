@@ -7,7 +7,8 @@ cluster with one GPU slot per chip, greedy chats through `ServingClient`
 -> `WebGateway` -> router -> `VLLMInstance` -> `LLMEngine` (scheduler,
 paged KV) -> `RealExecutor` -> `paged_model.decode_step` -> the Pallas
 paged-attention kernel. The engine is the one `repro.engine.factory`
-describes, built around the benchmark's own weights.
+describes, built around the benchmark's own weights, with the decode rows
+and KV blocks of the configuration's `deployment` where it gives them.
 
 The event loop runs free: its virtual delays cost no wall time. The
 benchmark wraps the program's entry calls to time them on the host:
@@ -103,24 +104,36 @@ class Probes:
         gateway.api_handle = api_handle
 
 
-DEPLOYMENT_KEYS = {"routing_policy", "about"}
+ENGINE_SIZES = ("max_num_seqs", "num_blocks")
+DEPLOYMENT_KEYS = {"routing_policy", "about", *ENGINE_SIZES}
 
 
 def deployment(spec: dict) -> dict:
-    """The configuration file's `deployment`; a key the harness does not
-    honour is refused."""
+    """The configuration file's `deployment`, with the engine sizes it
+    leaves out taken from `repro.engine.factory`; a key the harness does
+    not honour is refused."""
+    from repro.engine import factory as F
     dep = spec.get("deployment", {})
     unknown = set(dep) - DEPLOYMENT_KEYS
     if unknown:
         raise ValueError(f"{spec['name']}: deployment keys {sorted(unknown)}"
                          f" are not honoured; have {sorted(DEPLOYMENT_KEYS)}")
-    return dep
+    for k in ENGINE_SIZES:
+        v = dep.get(k)
+        if v is not None and (type(v) is not int or v < 1):
+            raise ValueError(f"{spec['name']}: deployment {k} {v!r} is not "
+                             f"a whole number of at least 1")
+    return {"max_num_seqs": F.MAX_NUM_SEQS, "num_blocks": F.NUM_BLOCKS,
+            **dep}
 
 
-def engine_factory(cfg, params, devices, *, hw, backend, probes: Probes):
+def engine_factory(cfg, params, devices, *, max_num_seqs: int,
+                   num_blocks: int, hw, backend, probes: Probes):
     """`ControlPlane`'s engine factory: the replica `repro.engine.factory`
-    describes (its sizes, `RealExecutor`, `LLMEngine`), around `params`,
-    on `devices[gpu]` for the job on GPU slot `gpu`."""
+    describes (`RealExecutor`, `LLMEngine`, its block size, context and
+    prefill budget), with `max_num_seqs` decode rows and `num_blocks` KV
+    blocks, around `params`, on `devices[gpu]` for the job on GPU slot
+    `gpu`."""
     import jax
     from repro.engine import factory as F
     from repro.engine.engine import LLMEngine
@@ -136,12 +149,12 @@ def engine_factory(cfg, params, devices, *, hw, backend, probes: Probes):
         if dev not in params_on:
             params_on[dev] = jax.block_until_ready(
                 jax.device_put(params, dev))
-        ex = RealExecutor(cfg, params_on[dev], num_blocks=F.NUM_BLOCKS,
+        ex = RealExecutor(cfg, params_on[dev], num_blocks=num_blocks,
                           block_size=F.BLOCK_SIZE, hw=hw, tp=tp,
                           backend=backend, max_model_len=F.MAX_MODEL_LEN,
-                          max_slots=F.MAX_NUM_SEQS, device=dev)
-        eng = LLMEngine(cfg, ex, num_blocks=F.NUM_BLOCKS,
-                        block_size=F.BLOCK_SIZE, max_num_seqs=F.MAX_NUM_SEQS,
+                          max_slots=max_num_seqs, device=dev)
+        eng = LLMEngine(cfg, ex, num_blocks=num_blocks,
+                        block_size=F.BLOCK_SIZE, max_num_seqs=max_num_seqs,
                         max_prefill_tokens=F.MAX_PREFILL_TOKENS,
                         max_model_len=F.MAX_MODEL_LEN)
         probes.wrap_executor(eng)

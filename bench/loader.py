@@ -1,16 +1,19 @@
 """Finding a cell's files by name: BENCHMARK.json at the checkout's root,
-then bench/configs, bench/traffic, bench/reference and bench/metrics."""
+then bench/configs, bench/traffic, bench/families, bench/reference and
+bench/metrics."""
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
 
 
 def load_module(path: Path):
-    """Import a file by path, under a name made from the path."""
+    """Import a file by path, under a name made from the path (registered,
+    as an import would be, so that dataclasses can read its namespace)."""
     path = Path(path)
     name = "bench_dyn_" + "_".join(path.with_suffix("").parts[-3:]) \
         .replace("-", "_").replace(".", "_")
@@ -18,8 +21,16 @@ def load_module(path: Path):
     if spec is None:
         raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+def family_module(root: Path, spec: dict):
+    """The module of the configuration file's model family,
+    bench/families/<family>.py; a file that names none is "dense"."""
+    return load_module(Path(root) / "bench" / "families"
+                       / f"{spec.get('family', 'dense')}.py")
 
 
 def benchmark(root: Path = ROOT) -> dict:

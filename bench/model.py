@@ -4,10 +4,10 @@ weights made from the seed.
 The weights are made by the benchmark, not by the program, so that the
 reference can make the same ones without taking anything from the program.
 They are made on the device in one jitted call, in the type they are
-served in, and laid out as the program's parameter tree for the dense
-family: embedding/tok (V, d) [and embedding/unembed (d, V) when the
-embeddings are not tied], layers/* stacked over the layer axis,
-final_norm (d,).
+served in. What depends on the model family (the program's configuration,
+the weight tree and the sizes the work counts need) is in the module that
+the file's `family` names, bench/families/<family>.py, "dense" where the
+file names none.
 """
 from __future__ import annotations
 
@@ -17,10 +17,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench.work import Shape
-
-EMBED_STD = 0.02
-NORM_STD = 0.05
+from bench.loader import ROOT, family_module, load_module
 
 
 def load(path) -> dict:
@@ -33,30 +30,15 @@ def head_dim(spec: dict) -> int:
                or spec["hidden_size"] // spec["num_attention_heads"])
 
 
-def shape(spec: dict) -> Shape:
-    return Shape(num_layers=spec["num_hidden_layers"],
-                 d_model=spec["hidden_size"],
-                 num_heads=spec["num_attention_heads"],
-                 num_kv_heads=spec["num_key_value_heads"],
-                 head_dim=head_dim(spec), d_ff=spec["intermediate_size"],
-                 vocab_size=spec["vocab_size"])
+def shape(spec: dict, root: Path = ROOT):
+    """The sizes the work counts (bench/work.py) take, as a
+    `bench.work.Shape` of the file's family."""
+    return family_module(root, spec).shape(spec)
 
 
-def model_config(spec: dict):
-    """The program's ModelConfig for this file (dense family)."""
-    from repro.config import ModelConfig
-    return ModelConfig(
-        name=spec["name"], family="dense",
-        num_layers=spec["num_hidden_layers"], d_model=spec["hidden_size"],
-        num_heads=spec["num_attention_heads"],
-        num_kv_heads=spec["num_key_value_heads"],
-        d_ff=spec["intermediate_size"], vocab_size=spec["vocab_size"],
-        head_dim=head_dim(spec), qk_norm=bool(spec["qk_norm"]),
-        rope_theta=float(spec["rope_theta"]),
-        tie_embeddings=bool(spec["tie_word_embeddings"]),
-        norm_eps=float(spec["rms_norm_eps"]),
-        max_position_embeddings=spec["max_position_embeddings"],
-        param_dtype=spec["torch_dtype"])
+def model_config(spec: dict, root: Path = ROOT):
+    """The program's ModelConfig for this file."""
+    return family_module(root, spec).model_config(spec)
 
 
 def seed_key(seed: int, stream: str):
@@ -79,53 +61,12 @@ def seed_words(seed: int) -> list:
             return words
 
 
-def _weights(key, s: Shape, tied: bool, qk_norm: bool, dtype):
-    import jax
-    import jax.numpy as jnp
-    keys = iter(jax.random.split(key, 16))
-    L, d, h, kv, hd, ff, v = (s.num_layers, s.d_model, s.num_heads,
-                              s.num_kv_heads, s.head_dim, s.d_ff,
-                              s.vocab_size)
-
-    # drawn in the served type, so no float32 copy of a leaf is held
-    def normal(shape, std):
-        return jax.random.normal(next(keys), shape, dtype) * std
-
-    def norm(shape):
-        return 1 + jax.random.normal(next(keys), shape, dtype) * NORM_STD
-
-    attn = {"wq": normal((L, d, h, hd), d ** -0.5),
-            "wk": normal((L, d, kv, hd), d ** -0.5),
-            "wv": normal((L, d, kv, hd), d ** -0.5),
-            "wo": normal((L, h, hd, d), (h * hd) ** -0.5)}
-    if qk_norm:
-        attn["q_norm"] = norm((L, hd))
-        attn["k_norm"] = norm((L, hd))
-    params = {
-        "embedding": {"tok": normal((v, d), EMBED_STD)},
-        "layers": {
-            "attn": attn,
-            "mlp": {"w_gate": normal((L, d, ff), d ** -0.5),
-                    "w_up": normal((L, d, ff), d ** -0.5),
-                    "w_down": normal((L, ff, d), ff ** -0.5)},
-            "ln1": norm((L, d)), "ln2": norm((L, d))},
-        "final_norm": norm((d,)),
-    }
-    if not tied:
-        params["embedding"]["unembed"] = normal((d, v), d ** -0.5)
-    return params
-
-
-def make_weights(spec: dict, seed: int, device):
+def make_weights(spec: dict, seed: int, device, root: Path = ROOT):
     """The weights of `spec` from `seed`, on `device`, in one jitted
     call."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
-    fn = jax.jit(partial(_weights, s=shape(spec),
-                         tied=bool(spec["tie_word_embeddings"]),
-                         qk_norm=bool(spec["qk_norm"]),
-                         dtype=jnp.dtype(spec["torch_dtype"])),
+    fn = jax.jit(partial(family_module(root, spec).weights, spec=spec),
                  out_shardings=SingleDeviceSharding(device))
     with jax.default_device(device):
         key = seed_key(seed, "weights")
@@ -134,5 +75,4 @@ def make_weights(spec: dict, seed: int, device):
 
 def reference_module(root: Path, spec: dict):
     """The plain reference named by the configuration file."""
-    from bench.loader import load_module
     return load_module(root / "bench" / "reference" / f"{spec['reference']}.py")

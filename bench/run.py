@@ -73,11 +73,12 @@ def compile_cache(root: Path):
 
 def run_cell(root: Path, workload: str, seed: int, seconds: float,
              trace: bool, *, t_start: float, require_chip: bool = True,
-             backend: Optional[str] = None, hw=None) -> dict:
+             backend: Optional[str] = None, hw=None, peaks=None) -> dict:
     """One run; returns the result line's object and, under "_summary",
     "_records" and "_served", what the clients saw and what each replica
     served. Off the chip (tests) pass `require_chip=False`, a
-    paged-attention `backend` and the roofline constants `hw`."""
+    paged-attention `backend`, the roofline constants `hw` and, for the
+    readers that count work against a chip's peaks, `peaks`."""
     import jax
     from bench import check, loader, model, traffic
     from bench.client import Clients, summarize
@@ -101,16 +102,16 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     if require_chip:
         from repro.config import hardware_for
         hw, peaks = hardware_for(dev), peaks_for(dev.device_kind)
-    else:
-        peaks = None
     words = model.seed_words(seed)
     probes = Probes(annotate=jax.profiler.TraceAnnotation if trace else None)
     jax.monitoring.register_event_listener(probes.on_event)
     jax.monitoring.register_event_duration_secs_listener(probes.on_event)
 
-    cfg = model.model_config(spec)
-    params = model.make_weights(spec, seed, dev)
-    plane = Plane(cfg, engine_factory(cfg, params, used, hw=hw,
+    cfg = model.model_config(spec, root)
+    params = model.make_weights(spec, seed, dev, root)
+    plane = Plane(cfg, engine_factory(cfg, params, used,
+                                      max_num_seqs=dep["max_num_seqs"],
+                                      num_blocks=dep["num_blocks"], hw=hw,
                                       backend=backend, probes=probes),
                   hw, probes, replicas=len(used),
                   routing_policy=dep.get("routing_policy"))
@@ -118,7 +119,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     kv_bytes = plane.engines[0].executor.pool["k"].dtype.itemsize
     q_bytes = jax.numpy.dtype(spec["torch_dtype"]).itemsize
     plane.warm_up(traffic.warm_up_requests(mix, spec["vocab_size"]),
-                  F.MAX_NUM_SEQS * len(used))
+                  dep["max_num_seqs"] * len(used))
     clients = Clients(
         plane.client, cfg.name,
         traffic.requests(mix, spec["vocab_size"], words),
@@ -165,7 +166,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     t_check = time.perf_counter()
-    weights = model.make_weights(spec, seed, dev)
+    weights = model.make_weights(spec, seed, dev, root)
     result = check.run(model.reference_module(root, spec), weights, spec,
                        records, words, F.MAX_MODEL_LEN,
                        float(spec["check"]["max_logit_gap"]))
@@ -175,7 +176,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     if trace:
         reading = Reading(steps=probes.steps, gateway_s=probes.gateway_s,
                           lowered=probes.lowered, window_s=t1 - t0,
-                          trace=summ, shape=model.shape(spec), peaks=peaks,
+                          trace=summ, shape=model.shape(spec, root),
+                          peaks=peaks,
                           chips=cell["chips"], kv_bytes=kv_bytes,
                           q_bytes=q_bytes)
         metrics = {}

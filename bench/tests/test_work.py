@@ -1,11 +1,13 @@
-"""bench/work.py and bench/peaks.py against hand counts at a tiny shape:
-2 layers, d_model 8, 2 query heads and 1 KV head of 4, d_ff 16, vocab 32.
+"""bench/work.py and bench/peaks.py against hand counts at a tiny dense
+shape (bench/families/dense.py): 2 layers, d_model 8, 2 query heads and
+1 KV head of 4, d_ff 16, vocab 32.
 """
 import pytest
 
-from bench import peaks, work
+from bench import loader, peaks, work
 
-S = work.Shape(num_layers=2, d_model=8, num_heads=2, num_kv_heads=1,
+DenseShape = loader.family_module(loader.ROOT, {}).DenseShape
+S = DenseShape(num_layers=2, d_model=8, num_heads=2, num_kv_heads=1,
                head_dim=4, d_ff=16, vocab_size=32)
 
 
@@ -35,6 +37,13 @@ def test_paged_attention_counts_real_context_only():
                                               q_bytes=2)
     assert flops == 320 + 192
     assert nbytes == 2 * (32 + 2 * 5 * 16) + 2 * (32 + 2 * 3 * 16)
+
+
+def test_a_shape_without_a_family_counts_no_feed_forward_work():
+    s = work.Shape(num_layers=2, d_model=8, num_heads=2, num_kv_heads=1,
+                   head_dim=4, vocab_size=32)
+    with pytest.raises(NotImplementedError, match="feed-forward"):
+        work.decode_flops(s, 5)
 
 
 def test_least_time_names_its_bound():

@@ -57,9 +57,11 @@ def make_root(tmp: Path, spec: dict = None, mix: dict = None) -> Path:
 def run(root: Path, seconds: float = 6.0, trace: bool = False,
         seed: int = 2**31 + 7, cell: str = CELL) -> dict:
     """The whole run on the CPU: the harness's look for a chip skipped,
-    the jnp paged-attention kernel, v5e roofline constants."""
+    the jnp paged-attention kernel, v5e roofline constants and peaks."""
+    from bench.peaks import peaks_for
     from bench.run import run_cell
     from repro.config import TPU_V5E
     return run_cell(root, cell, seed, seconds, trace,
                     t_start=time.perf_counter(), require_chip=False,
-                    backend="ref", hw=TPU_V5E)
+                    backend="ref", hw=TPU_V5E,
+                    peaks=peaks_for("TPU v5 lite"))

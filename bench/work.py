@@ -14,23 +14,30 @@ from dataclasses import dataclass
 
 @dataclass(frozen=True)
 class Shape:
-    """The sizes of a dense decoder that the counts need."""
+    """The attention and vocabulary sizes of a decoder that the counts
+    need. Each model family (bench/families/<family>.py) extends it with
+    the sizes of its feed-forward part and counts that part's work in
+    `ffn_flops`."""
     num_layers: int
     d_model: int
     num_heads: int
     num_kv_heads: int
     head_dim: int
-    d_ff: int
     vocab_size: int
+
+    def ffn_flops(self) -> int:
+        """Weight-matmul FLOPs of one layer's feed-forward part for one
+        token."""
+        raise NotImplementedError(f"{type(self).__name__} counts no "
+                                  f"feed-forward work")
 
 
 def layer_matmul_flops(s: Shape) -> int:
     """Weight-matmul FLOPs of one layer for one token: q, k, v and output
-    projections and the gated MLP (gate, up, down)."""
+    projections and the family's feed-forward part."""
     qo = 2 * 2 * s.d_model * s.num_heads * s.head_dim
     kv = 2 * 2 * s.d_model * s.num_kv_heads * s.head_dim
-    mlp = 3 * 2 * s.d_model * s.d_ff
-    return qo + kv + mlp
+    return qo + kv + s.ffn_flops()
 
 
 def attention_flops(s: Shape, context: int) -> int:

@@ -7,5 +7,5 @@ CONFIG = ModelConfig(
     d_ff=768, vocab_size=151_936, head_dim=128,
     qk_norm=True, rope_theta=1_000_000.0,
     num_experts=128, num_experts_per_tok=8, moe_d_ff=768,
-    param_dtype="bfloat16",
+    max_position_embeddings=40_960, param_dtype="bfloat16",
 )

@@ -2,10 +2,11 @@
 
 RealExecutor   — actual JAX compute on one device against the paged pool
                  (dense/vlm/moe) or slot-dense caches (ssm/hybrid/audio).
-                 On a TPU it runs the compiled Pallas paged-attention
-                 kernel; tests and the CPU rehearsal name `ref` or
-                 `interpret` instead. One replica holds one whole device:
-                 nothing is sharded across chips yet.
+                 On a TPU it runs the compiled Pallas kernels (paged
+                 attention, and the moe family's routed experts); tests
+                 and the CPU rehearsal name `ref` or `interpret`
+                 instead. One replica holds one whole device: nothing is
+                 sharded across chips yet.
 SimExecutor    — no compute; the roofline cost model supplies step times and
                  the engine synthesises token ids. Used by the Table-1-scale
                  virtual-clock benchmarks (50 runs × 1000 concurrency would
@@ -89,7 +90,7 @@ class RealExecutor:
         self.api = api
 
         def prefill(params, batch):
-            return api.prefill_fn(params, cfg, batch)
+            return api.prefill_fn(params, cfg, batch, backend=backend)
 
         # one program per prompt length, traced and compiled on its first
         # call: run eagerly, the layer scan is traced, lowered and compiled
@@ -169,12 +170,19 @@ class RealExecutor:
         n = len(dec["slots"])
         with HOST_SPANS.span("executor.decode.dispatch",
                              replica=self.replica, rows=n):
-            logits = self._dispatch_decode(dec)
+            logits, groups = self._dispatch_decode(dec)
         with HOST_SPANS.span("executor.decode.fetch", replica=self.replica,
-                             rows=n):
+                             rows=n) as span:
             # cut on the host: a slice on the device is one more program
             # for each batch size, lowered the first time the size comes
-            return np.asarray(logits)[:n]
+            out = np.asarray(logits)[:n]
+            if groups is not None and HOST_SPANS.on:
+                # the moe family's (layer, expert) group sizes: rows routed
+                # over all layers, and the experts given at least one
+                g = np.asarray(groups)
+                span.set(moe_rows=int(g.sum()),
+                         moe_active=int(np.count_nonzero(g)))
+            return out
 
     def _dispatch_decode(self, dec: dict):
         slots, tokens, pos = dec["slots"], dec["tokens"], dec["pos"]
@@ -191,10 +199,12 @@ class RealExecutor:
             for i, table in enumerate(dec["block_tables"]):
                 bt[i, :len(table)] = table
             pad = [0] * (self.max_slots - n)
-            logits, self.pool = self._decode_program(
+            # the moe family's step also returns its (layer, expert)
+            # group sizes
+            logits, self.pool, *groups = self._decode_program(
                 self.params, self._put(list(tokens) + pad),
                 self._put(list(pos) + pad), self.pool, self._put(bt))
-            return logits
+            return logits, (groups[0] if groups else None)
         toks = self._put(tokens)
         posv = self._put(pos)
         # state executor: gather slot caches, run decode_fn, scatter back
@@ -205,4 +215,4 @@ class RealExecutor:
         self.cache = jax.tree.map(
             lambda slab, c: slab.at[:, sl].set(c.astype(slab.dtype)),
             self.cache, cache)
-        return logits
+        return logits, None

@@ -12,6 +12,7 @@ tiles.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -56,13 +57,24 @@ def write_prefill(pool, cache, block_table, block_size: int):
 def decode_step(params, cfg: ModelConfig, tokens, pos, pool, block_tables,
                 *, backend: str):
     """tokens/pos: (S,); pool as init_pool; block_tables: (S, MB).
-    Returns (logits (S, V), new pool)."""
+    Returns (logits (S, V), new pool), and for the moe family a third
+    item, the per-layer group sizes (L, E) int32 of the live rows: the rows
+    past position 0, since a sequence decodes only after its prompt, and
+    the rows that pad a batch sit at 0. The moe family computes in
+    float32 (`moe.SERVE_DTYPE`, `moe.serving_precision`), its logits
+    float32. The kernels run on `backend`."""
     x = cm.embed(params["embedding"], tokens[:, None])   # (S, 1, d)
     s = tokens.shape[0]
     bs = pool["k"].shape[3]
     blk = jnp.take_along_axis(block_tables, (pos // bs)[:, None], axis=1)[:, 0]
     off = pos % bs
     ctx = pos + 1
+    layers, experts = params["layers"], None
+    numerics = contextlib.nullcontext()
+    if cfg.family == "moe":     # served in float32 (models/moe.py)
+        layers, experts = moe_mod.split_experts(layers)
+        x = x.astype(moe_mod.SERVE_DTYPE)
+        numerics = moe_mod.serving_precision()
 
     def body(x, inp):
         lp, pk, pv = inp
@@ -76,12 +88,17 @@ def decode_step(params, cfg: ModelConfig, tokens, pos, pool, block_tables,
         x = x + jnp.einsum("shd,hdo->so", a, lp["attn"]["wo"])[:, None]
         h = cm.rms_norm(x, lp["ln2"], cfg.norm_eps)
         if cfg.family == "moe":
-            y, _ = moe_mod.moe_block(lp["moe"], cfg, h, capacity_factor=None)
-            x = x + y
-        else:
-            x = x + cm.mlp(lp["mlp"], h)
+            y, groups = moe_mod.serve_experts(
+                dict(lp["moe"], **experts), cfg, h, backend=backend,
+                routed=pos > 0, layer=lp["layer"])
+            return x + y, {"k": pk, "v": pv, "groups": groups}
+        x = x + cm.mlp(lp["mlp"], h)
         return x, {"k": pk, "v": pv}
 
-    x, pool = lax.scan(body, x, (params["layers"], pool["k"], pool["v"]))
-    x = cm.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return cm.unembed(params["embedding"], x)[:, 0], pool
+    with numerics:
+        x, pool = lax.scan(body, x, (layers, pool["k"], pool["v"]))
+        x = cm.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = cm.unembed(params["embedding"], x)[:, 0]
+    if cfg.family == "moe":
+        return logits, {"k": pool["k"], "v": pool["v"]}, pool["groups"]
+    return logits, pool

@@ -75,13 +75,17 @@ def loss_fn(params, cfg: ModelConfig, batch):
 # serving
 # --------------------------------------------------------------------------
 
-def prefill_fn(params, cfg: ModelConfig, batch):
+def prefill_fn(params, cfg: ModelConfig, batch, backend: str = "ref"):
+    """`backend` runs the moe family's expert kernel (kernels/moe_experts);
+    the other families have no kernel in prefill."""
     tokens = batch["tokens"]
     if cfg.family == "audio":
         return whisper.prefill(params, cfg, tokens, batch["frames"])
     if cfg.family == "vlm":
         return transformer.prefill(params, cfg, tokens,
                                    patch_embeds=batch["patch_embeds"])
+    if cfg.family == "moe":
+        return moe.prefill(params, cfg, tokens, backend=backend)
     return module_for(cfg).prefill(params, cfg, tokens)
 
 

@@ -1,11 +1,12 @@
 """Compile the Pallas kernels of the served path for a described TPU v5e.
 
-Interpret mode (tests/test_kernels.py) cannot see Mosaic's tiling rules or
-its VMEM limit; the TPU compiler, installed here, compiles for a chip that
-is described and not attached. Widths are qwen3-1.7b's: 16 query heads,
-8 KV heads, head_dim 128, with the served pool (512 blocks, 1024-token
-sequences). Nothing runs, so nothing here says anything about results or
-time.
+Interpret mode (tests/test_kernels.py, tests/test_moe_serving.py) cannot
+see Mosaic's tiling rules or its VMEM limit; the TPU compiler, installed
+here, compiles for a chip that is described and not attached. Attention
+widths are qwen3-1.7b's: 16 query heads, 8 KV heads, head_dim 128, with
+the served pool (512 blocks, 1024-token sequences); the routed experts
+are qwen3-moe-30b-a3b's: 128 of width 768 over d 2048, top-8. Nothing
+runs, so nothing here says anything about results or time.
 """
 import os
 
@@ -16,9 +17,11 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import configs
 from repro.kernels.flash_prefill.kernel import flash_prefill
+from repro.kernels.moe_experts.kernel import moe_experts
 from repro.kernels.paged_attention.kernel import paged_attention
 
 CFG = configs.get("qwen3-1.7b")
+MOE = configs.get("qwen3-moe-30b-a3b")
 NUM_BLOCKS, MAX_MODEL_LEN, MAX_SEQS = 512, 1024, 8
 
 
@@ -93,3 +96,26 @@ def test_kernel_names_survive_an_enclosing_program(one_chip):
     text = jax.jit(lambda q, k, v: flash_prefill.__wrapped__(
         q, k, v, interpret=False) + 1).lower(q, k, k).compile().as_text()
     assert re.search(r"%flash_prefill(\.\d+)? = \S+ custom-call", text)
+
+
+# rows: a decode batch of 32 rows x top-8, and a 1000-token prompt's; in
+# float32 as the moe family serves them, and in the weights' bf16
+@pytest.mark.parametrize("rows", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("m", [32 * 8, 1024 * 8])
+def test_moe_experts_compiles_for_v5e(one_chip, m, rows):
+    """Gate and up blocks of 2048 x 768 in bf16 fit the kernel's VMEM, one
+    layer of six is read from the stacked weights, and both calls keep the
+    name `moe_experts_roofline` reads inside an enclosing program."""
+    import re
+    layers, e, d, f = 6, MOE.num_experts, MOE.d_model, MOE.moe_d_ff
+    w_in = _sds((layers, e, d, f), jnp.bfloat16, one_chip)
+    args = (_sds((m, d), rows, one_chip), w_in, w_in,
+            _sds((layers, e, f, d), jnp.bfloat16, one_chip),
+            _sds((e,), jnp.int32, one_chip), _sds((), jnp.int32, one_chip))
+    compiled = jax.jit(lambda *a: moe_experts.__wrapped__(
+        *a, tm=128, interpret=False) * 2.0).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%moe_experts(\.\d+)? = \S+ custom-call",
+                          text)) == 2
+    # the stacked weights are read in place: no layer of them is copied
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * m * d * 4
